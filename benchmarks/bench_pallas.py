@@ -2,8 +2,8 @@
 
     PYTHONPATH=src python -m benchmarks.bench_pallas [--repeats N] [--force-interpret]
 
-Per stencil kernel (jacobi-1d, jacobi-2d, heat-3d), four measurements over
-identical inputs:
+Per stencil kernel (jacobi-1d, jacobi-2d, heat-3d), three measurements
+over identical inputs:
 
 * **fifo_ring** — `Analysis.compile(backend="pallas")` on the planned PPN:
   one fused kernel, every cross-block channel a VMEM scratch ring carried
@@ -11,11 +11,9 @@ identical inputs:
 * **addressable** — the same compiler forced to ``mode="addressable"``:
   one kernel launch per time step, the whole level round-tripping through
   HBM each time (the reorder-buffer cost model a non-FIFO plan forces);
-* **handwritten** — `kernels/stencil_fifo/jacobi_fifo` where one exists
-  (jacobi-1d only), the idiom the codegen generalizes;
 * **oracle** — the pure-jnp reference the outputs are checked against.
 
-Every recorded row requires (a) fifo_ring/addressable/handwritten outputs
+Every recorded row requires (a) fifo_ring/addressable outputs
 allclose to the oracle, and (b) `Analysis.validate(backend="pallas")` green —
 the same planned traces replayed through real VMEM rings, positive AND
 negative directions.  The script REFUSES to write results otherwise.
@@ -51,17 +49,17 @@ BENCH_PATH = Path(__file__).resolve().parent.parent / "BENCH_pallas.json"
 DESCRIPTION = (
     "Generated fused VMEM-ring kernels (Analysis.compile(backend='pallas') "
     "over the planned PPN) vs the addressable per-timestep HBM-round-trip "
-    "fallback and the hand-written stencil_fifo kernel, outputs checked "
+    "fallback, outputs checked "
     "against the pure-jnp oracles and every plan replayed through "
     "Analysis.validate(backend='pallas') positively and negatively. "
     "execution_mode says whether timings are TPU silicon or Pallas "
     "interpret mode (off-TPU CI). "
     "Regenerate with: PYTHONPATH=src python -m benchmarks.bench_pallas")
 
-#: kernel → (input shape, time steps, streamed-axis block).  steps == block
-#: for jacobi-1d so the hand-written kernel's constraint is satisfiable.
+#: kernel → (input shape, time steps, streamed-axis block).  Blocks fill
+#: whole lanes (jacobi-1d) or sublane rows (jacobi-2d), as a TPU needs.
 GEOMETRIES = {
-    "jacobi-1d": ((4096,), 64, 64),
+    "jacobi-1d": ((4096,), 128, 128),
     "jacobi-2d": ((256, 64), 32, 32),
     "heat-3d": ((64, 16, 16), 16, 16),
 }
@@ -102,12 +100,6 @@ def run_kernel(name: str, repeats: int, interpret: Optional[bool]) -> dict:
 
     record("fifo_ring", lambda: ring(x, steps, block))
     record("addressable", lambda: buf(x, steps, block))
-    if name == "jacobi-1d":
-        from repro.kernels.stencil_fifo import jacobi_fifo
-        hw_interpret = default_interpret() if interpret is None else interpret
-        record("handwritten",
-               lambda: jacobi_fifo(x, steps=steps, block=block,
-                                   interpret=hw_interpret))
 
     # the acceptance gate: the same planned traces through real VMEM rings,
     # positive and negative directions
@@ -128,10 +120,6 @@ def run_kernel(name: str, repeats: int, interpret: Optional[bool]) -> dict:
         "validate": {"backend": "pallas", "replays": v.replays,
                      "negative_rejections": v.rejections},
     }
-    hw = runs.get("handwritten")
-    if hw:
-        row["ring_vs_handwritten"] = round(
-            hw["seconds"] / runs["fifo_ring"]["seconds"], 2)
     return row
 
 
@@ -144,11 +132,9 @@ def run(repeats: int, interpret: Optional[bool]) -> dict:
         row = run_kernel(name, repeats, interpret)
         rows.append(row)
         r = row["runs"]
-        hw = (f" handwritten {r['handwritten']['seconds']*1e3:8.1f}ms"
-              if "handwritten" in r else "")
         print(f"{name:10s} ring {r['fifo_ring']['seconds']*1e3:8.1f}ms "
               f"addressable {r['addressable']['seconds']*1e3:8.1f}ms "
-              f"({row['ring_vs_addressable_speedup']:5.1f}x){hw}  "
+              f"({row['ring_vs_addressable_speedup']:5.1f}x)  "
               f"validate {row['validate']['replays']} replays /"
               f" {row['validate']['negative_rejections']} rejections")
     return {

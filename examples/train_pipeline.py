@@ -24,8 +24,11 @@ from repro.comm.pipeline import pipeline_train_step
 def main():
     from jax.sharding import Mesh
 
-    n_dev = len(jax.devices())
-    S = min(4, n_dev)
+    S = 4
+    if len(jax.devices()) < S:
+        raise SystemExit(f"{S} pipeline stages need {S} devices, found "
+                         f"{len(jax.devices())} (on the CPU: XLA_FLAGS="
+                         f"--xla_force_host_platform_device_count={S})")
     mesh = Mesh(np.array(jax.devices())[:S], ("pipe",))
     M, mb, D = 8, 4, 32
 

@@ -31,9 +31,7 @@ import numpy as np
 
 
 def _flatten(tree) -> Tuple[List[Tuple[str, Any]], Any]:
-    # jax.tree.flatten_with_path only exists on newer jax; the tree_util
-    # spelling works on every version this repo supports
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     return [(jax.tree_util.keystr(path), leaf) for path, leaf in flat], treedef
 
 

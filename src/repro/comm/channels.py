@@ -19,16 +19,9 @@ import jax
 import jax.numpy as jnp
 
 
-def _axis_size(axis: str) -> int:
-    """Static size of a named mesh axis, across jax versions (`lax.axis_size`
-    is recent; `psum(1, axis)` constant-folds to the size everywhere)."""
-    size_fn = getattr(jax.lax, "axis_size", None)
-    return size_fn(axis) if size_fn is not None else jax.lax.psum(1, axis)
-
-
 def fifo_shift(x, axis: str, shift: int = 1, wrap: bool = False):
     """Send x to the next device along `axis` (FIFO neighbor stream)."""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if wrap:
         perm = [(i, (i + shift) % n) for i in range(n)]
     else:

@@ -27,22 +27,6 @@ from ..runtime.lowering import (FIFO_STREAM, REORDER_BUFFER, backend,
                                 is_cheap)
 
 
-def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
-    """`jax.shard_map` across jax versions (the kwarg disabling the
-    replication/varying-manual-axes check was renamed, and older releases
-    only ship the experimental entry point)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            pass
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 def ring_lowering(plans: Iterable) -> str:
     """The single lowering a rotating ring needs to serve every planned
     channel: the cheap ppermute stream iff every `ChannelPlan` record is a
@@ -113,10 +97,7 @@ def pipeline_loss_fn(stage_fn: Callable, loss_head: Callable, mesh: Mesh,
         M = xs.shape[0]
         T = M + n - 1                        # pipeline ticks
         h = jnp.zeros_like(xs[0])
-        # rank-1 (not scalar) and derived from xs: the pre-0.4.38 shard_map
-        # transpose assigns malformed axis names to rank-0 scan-carry
-        # cotangents, and mis-handles hoisted scalar constants
-        loss_acc = (jnp.sum(xs[0]) * 0.0).astype(jnp.float32)[None]
+        loss_acc = jnp.zeros((), jnp.float32)
 
         def tick(carry, t):
             h, loss_acc = carry
@@ -132,20 +113,18 @@ def pipeline_loss_fn(stage_fn: Callable, loss_head: Callable, mesh: Mesh,
             mb_loss = loss_head(h_out, tgt)
             take = jnp.logical_and(stage == n - 1,
                                    jnp.logical_and(out_id >= 0, out_id < M))
-            # mask-multiply, not where(take, ., 0.0): see loss_acc note above
-            loss_acc = loss_acc + take.astype(mb_loss.dtype) * mb_loss
+            loss_acc = loss_acc + jnp.where(take, mb_loss, 0.0)
             # stage s → s+1 channel: one registry-selected lowering step
             h_next = step.step(h_out, axis, stage, n)
             return (h_next, loss_acc), None
 
         (h, loss_acc), _ = jax.lax.scan(tick, (h, loss_acc), jnp.arange(T))
         # every stage returns the (replicated) total loss
-        loss = jax.lax.psum(loss_acc[0], axis) / M
+        loss = jax.lax.psum(loss_acc, axis) / M
         return loss
 
-    return _shard_map(inner, mesh,
-                      in_specs=(P(axis), P(), P()),
-                      out_specs=P())
+    return jax.shard_map(inner, mesh=mesh, in_specs=(P(axis), P(), P()),
+                         out_specs=P(), check_vma=False)
 
 
 def pipeline_train_step(stage_fn, loss_head, mesh: Mesh, axis: str = "pipe",

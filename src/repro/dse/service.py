@@ -26,11 +26,28 @@ import time
 from typing import Any, Dict, List, Mapping, Optional, Union
 
 from .experiment import Experiment
-from .managers import ExecutionManager, make_manager
+from .managers import ExecutionManager, InlineManager, make_manager
 from .pareto import frontier_by_kernel, frontier_summary
 from .store import ArtifactStore
 
 SCHEMA = "repro-dse-run-v1"
+
+
+def _check_measure_manager(experiment: Experiment,
+                           manager: Union[str, ExecutionManager]) -> None:
+    """A chip belongs to one process.  On a TPU host, every pool or
+    subprocess worker that times a kernel would reach for it, so an
+    experiment that measures runs inline there."""
+    if not experiment.measure or manager == "inline" \
+            or isinstance(manager, InlineManager):
+        return
+    import jax
+    if jax.default_backend() == "tpu":
+        name = manager if isinstance(manager, str) \
+            else type(manager).__name__
+        raise ValueError(
+            f"manager {name!r} cannot measure on a TPU host: each worker "
+            f"process would claim the chip; use manager='inline'")
 
 
 class DSEService:
@@ -38,6 +55,7 @@ class DSEService:
                  store: Optional[ArtifactStore] = None,
                  manager: Union[str, ExecutionManager] = "inline",
                  manager_kwargs: Optional[Mapping[str, Any]] = None):
+        _check_measure_manager(experiment, manager)
         self.experiment = experiment
         self.store = store or ArtifactStore()
         self._manager = manager

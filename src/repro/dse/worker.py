@@ -21,7 +21,8 @@ Every successful point carries:
   (`repro.launch.roofline.predict_report_cost`);
 * ``measured`` — where requested and the pallas backend applies
   (`STENCIL_PROGRAMS`), wall-clock seconds of the generated kernel
-  (`measure_compiled`) with its geometry; absent otherwise;
+  (`measure_compiled`) with its geometry; absent otherwise.  A kernel that
+  fails to compile or run makes the point an error result;
 * ``provenance`` — how the number was produced: ``size_mode`` actually used
   per point, fallback reasons, applied lowering overrides, seconds spent.
 """
@@ -204,15 +205,12 @@ def run_group(task_doc: Mapping[str, Any]) -> List[Dict[str, Any]]:
                     analysis, _ = _evaluate_concrete(   # Analysis object
                         case.kernel, point.sizes, cfg, task.topology,
                         task.pow2)
-                try:
-                    m = _measure_point(task.kernel, analysis, point.sizes,
-                                       cfg, task.measure)
-                    if m is not None:
-                        row["measured"] = m
-                        row["metrics"]["measured_s"] = m["seconds"]
-                except Exception as e:          # bad geometry: skip, loudly
-                    row["provenance"]["notes"].append(
-                        f"measure skipped: {type(e).__name__}: {e}")
+                # a kernel that cannot compile or run is this point's error
+                m = _measure_point(task.kernel, analysis, point.sizes, cfg,
+                                   task.measure)
+                if m is not None:
+                    row["measured"] = m
+                    row["metrics"]["measured_s"] = m["seconds"]
         except Exception as e:
             row["error"] = _error_doc(e)
             row["provenance"] = {

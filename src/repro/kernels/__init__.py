@@ -1,8 +1,8 @@
 """Pallas TPU kernels for the compute hot-spots (validated in interpret mode
 on CPU; selected on TPU by ops.py wrappers):
 
-    stencil_fifo     — the paper's Fig. 3 tiled stencil with VMEM FIFO
-                       channels (HBM traffic T·2N → 2N)
+    stencil_bands    — pure-jnp oracles of the band stencils whose fused
+                       VMEM-ring kernels `runtime.pallas_codegen` generates
     flash_attention  — blocked causal GQA attention (triangular block skip,
                        online softmax in VMEM scratch)
     gla_timemix      — chunkwise-parallel RWKV-6/GLA core: (hd×hd) fp32

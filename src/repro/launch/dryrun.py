@@ -30,9 +30,8 @@ from ..models.sharding import Rules
 from ..train.step import (make_abstract_train_state, make_train_state_specs,
                           make_train_step)
 from . import hlo_stats
+from .cache import use_compile_cache
 from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh
-
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
 
 
 def skip_reason(arch: str, cell: ShapeCell) -> Optional[str]:
@@ -153,8 +152,6 @@ def run_cell(arch: str, cell_name: str, multi_pod: bool, out_dir: pathlib.Path,
 
         mem = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, list):        # older jax wraps the dict in a list
-            ca = ca[0] if ca else {}
         txt = compiled.as_text()
         st = hlo_stats.analyze(txt)
         print(mem)
@@ -210,6 +207,7 @@ def main() -> None:
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     out_dir = pathlib.Path(args.out)
     out_dir.mkdir(parents=True, exist_ok=True)

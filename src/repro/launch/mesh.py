@@ -1,13 +1,16 @@
-"""Production mesh builders.
+"""Mesh builders.
 
-`make_production_mesh` is a FUNCTION (never a module-level constant) so that
-importing this module never touches jax device state.  Single-pod: 16×16
-(256 chips, TPU v5e pod); multi-pod: 2×16×16 = 512 chips, the "pod" axis
-crossing the data-center network.
+These are FUNCTIONS (never module-level constants) so that importing this
+module never touches jax device state.  `make_production_mesh` is the dry
+run's described fleet — single-pod: 16×16 (256 chips, TPU v5e pod);
+multi-pod: 2×16×16 = 512 chips, the "pod" axis crossing the data-center
+network.  `make_local_mesh` is what the launchers run on.
 """
 from __future__ import annotations
 
 import jax
+import numpy as np
+from jax.sharding import Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,9 +19,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes)
 
 
-def make_host_mesh(data: int = 2, model: int = 2):
-    """Small mesh over host devices, for tests/examples."""
-    return jax.make_mesh((data, model), ("data", "model"))
+def make_local_mesh() -> Mesh:
+    """(data, model) mesh over the devices this process sees, all on the
+    data axis: one chip gives 1×1, a four-chip host 4×1."""
+    return Mesh(np.array(jax.devices()).reshape(-1, 1), ("data", "model"))
 
 
 # TPU v5e hardware constants for the roofline (per chip)

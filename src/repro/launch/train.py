@@ -1,31 +1,32 @@
-"""Production training launcher.
+"""Training launcher, on a mesh of the devices this process sees.
 
-    PYTHONPATH=src python -m repro.launch.train --arch llama3-405b \
-        [--steps N] [--ckpt DIR] [--reduced] [--batch B --seq S]
+    PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
+        [--steps N] [--ckpt DIR] [--reduced]
 
-On a real fleet this binary runs per-host under the cluster scheduler
-(jax.distributed.initialize picks up the coordinator from env); on the CPU
-container use --reduced for a runnable smoke.
+Without ``--reduced`` the model runs at its published widths; ``--reduced``
+is the CPU smoke size.  On a fleet this binary runs per host under the
+cluster scheduler (``--distributed``: jax.distributed.initialize picks up
+the coordinator from env).
 """
 import argparse
 import logging
 
 import jax
-import numpy as np
-from jax.sharding import Mesh
 
 from .. import configs
 from ..configs.base import reduced as reduce_cfg
 from ..models import build
 from ..models.sharding import Rules
 from ..train.loop import train
+from .cache import CACHE_DIR, use_compile_cache
+from .mesh import make_local_mesh
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=configs.arch_names())
     ap.add_argument("--steps", type=int, default=200)
-    ap.add_argument("--ckpt", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt", default=str(CACHE_DIR.parent / "ckpt"))
     ap.add_argument("--reduced", action="store_true",
                     help="smoke-sized config (CPU)")
     ap.add_argument("--lr", type=float, default=3e-4)
@@ -33,6 +34,7 @@ def main() -> None:
                     help="call jax.distributed.initialize() (multi-host)")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    use_compile_cache()
 
     if args.distributed:
         jax.distributed.initialize()
@@ -43,12 +45,7 @@ def main() -> None:
     if args.reduced:
         par = par.replace(num_microbatches=2, optimizer_state_dtype="float32",
                           grad_accum_dtype="float32")
-        mesh = Mesh(np.array(jax.devices())[:1].reshape(1, 1),
-                    ("data", "model"))
-    else:
-        from .mesh import make_production_mesh
-        mesh = make_production_mesh()
-
+    mesh = make_local_mesh()
     model = build(cfg, par)
     rules = Rules.make(mesh, par)
     with mesh:

@@ -30,10 +30,12 @@ NEG = -1e30
 def attn_plan(cfg: ModelConfig) -> Dict:
     D, H, KV, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     p = {
-        "wq": PSpec((D, H, hd), ("wfsdp", "heads", None), "normal", 1.0),
-        "wk": PSpec((D, KV, hd), ("wfsdp", "kv_heads", None), "normal", 1.0),
-        "wv": PSpec((D, KV, hd), ("wfsdp", "kv_heads", None), "normal", 1.0),
-        "wo": PSpec((H, hd, D), ("heads", None, "wfsdp"), "normal", 1.0),
+        # fan-in is the contracted width (D in, H·hd out), not a head axis:
+        # drawn per head, scores grow with the width and softmax saturates
+        "wq": PSpec((D, H, hd), ("wfsdp", "heads", None), fan_in=D),
+        "wk": PSpec((D, KV, hd), ("wfsdp", "kv_heads", None), fan_in=D),
+        "wv": PSpec((D, KV, hd), ("wfsdp", "kv_heads", None), fan_in=D),
+        "wo": PSpec((H, hd, D), ("heads", None, "wfsdp"), fan_in=H * hd),
     }
     if cfg.qkv_bias:
         p["bq"] = PSpec((H, hd), ("heads", None), "zeros")

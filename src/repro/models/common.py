@@ -22,10 +22,11 @@ class PSpec:
     init: str = "normal"                    # normal | zeros | ones
     scale: float = 1.0                      # multiplier on fan-in init
     dtype: Any = jnp.bfloat16
+    fan_in: int = 0                         # 0: the second-to-last dim
 
     def stacked(self, layers: int) -> "PSpec":
         return PSpec((layers,) + self.shape, (None,) + self.names,
-                     self.init, self.scale, self.dtype)
+                     self.init, self.scale, self.dtype, self.fan_in)
 
 
 def is_pspec(x) -> bool:
@@ -54,9 +55,7 @@ def init_params(rng, plan):
     Python's hash() is per-process randomized and would make two processes
     initialize different models from the same seed)."""
     import zlib
-    # jax.tree_util spelling: jax.tree.flatten_with_path needs newer jax
-    flat, treedef = jax.tree_util.tree_flatten_with_path(plan,
-                                                         is_leaf=is_pspec)
+    flat, treedef = jax.tree.flatten_with_path(plan, is_leaf=is_pspec)
 
     def one(path, p: PSpec):
         if p.init == "zeros":
@@ -65,7 +64,8 @@ def init_params(rng, plan):
             return jnp.ones(p.shape, p.dtype)
         key = jax.random.fold_in(
             rng, zlib.crc32(jax.tree_util.keystr(path).encode()) % (2 ** 31))
-        fan_in = p.shape[-2] if len(p.shape) >= 2 else p.shape[-1]
+        fan_in = p.fan_in or (p.shape[-2] if len(p.shape) >= 2
+                              else p.shape[-1])
         std = p.scale / np.sqrt(max(fan_in, 1))
         return (std * jax.random.normal(key, p.shape, jnp.float32)).astype(p.dtype)
 

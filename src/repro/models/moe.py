@@ -134,13 +134,12 @@ def apply_moe(p, x, cfg: ModelConfig, rules: Rules) -> Tuple[jnp.ndarray, jnp.nd
             y_l = jax.lax.psum(y_l, ax)
         return y_l
 
-    from ..comm.pipeline import _shard_map
-    y = _shard_map(
-        combine_local, rules.mesh,
+    y = jax.shard_map(
+        combine_local, mesh=rules.mesh,
         in_specs=(pp(batch_part, expert_part, (), ()),
                   pp(batch_part, expert_part, ()),
                   pp(batch_part, expert_part, ())),
-        out_specs=pp(batch_part, (), ()),
+        out_specs=pp(batch_part, (), ()), check_vma=False,
     )(ye, disp, gate_buf)
     y = rules.constrain(y.reshape(B, S, D), "batch", "seq", "embed_act")
     return y.astype(x.dtype), aux.astype(jnp.float32)

@@ -1,20 +1,21 @@
-"""The ``"pallas"`` backend: lowering IR executed as real VMEM kernels.
+"""The ``"pallas"`` backend: lowering IR executed as Pallas kernels.
 
 Where the ``"reference"`` backend replays a channel's trace with numpy array
-ops, this backend replays the SAME trace through the memory structure the
-lowering actually buys on a TPU — a VMEM scratch ring addressed by a Pallas
-kernel (interpret-mode off-TPU, so CI exercises it everywhere):
+ops, this backend replays the SAME trace through a ring of slots addressed
+by a Pallas kernel — compiled on a TPU, interpret-mode elsewhere, so CI
+exercises it everywhere.  Each event touches one slot, so events and ring
+are SMEM words; a trace too long for SMEM raises `TraceTooLong`:
 
 * the ppermute family (`FIFO_STREAM` and both split variants) and the
   broadcast register run the trace's push/pop/retire events against a ring
-  of ``slots`` VMEM words, checking *in kernel* that every pop finds the
+  of ``slots`` words, checking *in kernel* that every pop finds the
   value it expects (an undersized ring gets clobbered and fails as
   `RingOverflow` — the negative direction `Analysis.validate` demands) and
   that the pop order is one the structure can serve (violations surface as
   the same `OrderViolation` the reference backend raises, so the validator's
   negative checks work unchanged on this backend);
 * the reorder buffer runs the same kernel with order checking disabled —
-  addressable VMEM scratch, any pop order, still capacity-checked.
+  addressable slots, any pop order, still capacity-checked.
 
 Event lists are built host-side from the dense-rank trace
 (`simulator.trace_channel`): pushes at key ``2·w_rank + 1``, retires at
@@ -60,7 +61,7 @@ _FIFO, _REGISTER, _REORDER = 0, 1, 2
 
 
 class RingOverflow(SimulationError):
-    """The VMEM ring was too small for the trace: a push clobbered a live
+    """The replay ring was too small for the trace: a push clobbered a live
     slot, or a pop read back a value the ring no longer held."""
 
 
@@ -123,20 +124,35 @@ def _pow2(n: int) -> int:
     return 1 << max(0, int(n - 1).bit_length())
 
 
+#: SMEM the replay kernel may fill: a TPU v5e core has 1 MiB of scalar
+#: memory, less a few KiB the compiler keeps; the three event arrays, the
+#: ring and the counters live there.
+SMEM_LIMIT_BYTES = 1024 * 1024 - 16 * 1024
+
+
+class TraceTooLong(ValueError):
+    """The trace's events and ring do not fit the replay kernel's SMEM."""
+
+
 def _replay_kernel(kind_ref, value_ref, slot_ref, o_ref, ring, *,
                    n_events: int, order_op: int):
-    """Execute the event list against a VMEM ring, counting every way the
-    structure can fail.  ``order_op`` is the pop discipline: _FIFO rejects
-    any pop that is not the next push position, _REGISTER any regression,
-    _REORDER nothing (addressable)."""
-    ring[...] = jnp.full_like(ring, -1)
+    """Execute the event list against a ring of scalar slots, counting
+    every way the structure can fail.  ``order_op`` is the pop discipline:
+    _FIFO rejects any pop that is not the next push position, _REGISTER any
+    regression, _REORDER nothing (addressable).  Events and ring are SMEM
+    words: each event touches one slot, which vector memory cannot address
+    without a whole tile."""
+
+    def clear(i, carry):
+        ring[i] = jnp.int32(-1)
+        return carry
+
+    jax.lax.fori_loop(0, ring.shape[0], clear, 0)
 
     def body(e, state):
         live, peak, last_p, order_v, mism, ovf, unf = state
-        k = pl.load(kind_ref, (pl.dslice(e, 1),))[0]
-        v = pl.load(value_ref, (pl.dslice(e, 1),))[0]
-        s = pl.load(slot_ref, (pl.dslice(e, 1),))[0]
-        cur = pl.load(ring, (pl.dslice(s, 1),))[0]
+        k, v, s = kind_ref[e], value_ref[e], slot_ref[e]
+        cur = ring[s]
         is_push = (k == _PUSH).astype(jnp.int32)
         is_pop = (k == _POP).astype(jnp.int32)
         is_retire = (k == _RETIRE).astype(jnp.int32)
@@ -153,17 +169,18 @@ def _replay_kernel(kind_ref, value_ref, slot_ref, o_ref, ring, *,
             bad = jnp.int32(0)
         order_v = order_v + is_pop * bad
         last_p = jnp.where(is_pop == 1, jnp.maximum(last_p, v), last_p)
-        new = jnp.where(is_push == 1, v, jnp.where(is_retire == 1, -1, cur))
-        pl.store(ring, (pl.dslice(s, 1),), new[None].astype(jnp.int32))
+        ring[s] = jnp.where(is_push == 1, v,
+                            jnp.where(is_retire == 1, -1, cur))
         live = live + is_push - is_retire
         peak = jnp.maximum(peak, live)
         return live, peak, last_p, order_v, mism, ovf, unf
 
     zero = jnp.int32(0)
     init = (zero, zero, jnp.int32(-1), zero, zero, zero, zero)
-    live, peak, _, order_v, mism, ovf, unf = jax.lax.fori_loop(
-        0, n_events, body, init, unroll=False)
-    o_ref[...] = jnp.stack([peak, order_v, mism, ovf, unf, live])
+    out = jax.lax.fori_loop(0, n_events, body, init, unroll=False)
+    live, peak, _, order_v, mism, ovf, unf = out
+    for i, x in enumerate((peak, order_v, mism, ovf, unf, live)):
+        o_ref[i] = x
 
 
 @functools.lru_cache(maxsize=None)
@@ -171,11 +188,20 @@ def _replay_call(n_events: int, ring_size: int, order_op: int,
                  interpret: bool):
     """Compiled replay kernel, cached on the pow2-padded shape bucket so
     channels of similar size share one compilation."""
+    need = 4 * (3 * n_events + ring_size + 6)
+    if need > SMEM_LIMIT_BYTES:
+        raise TraceTooLong(
+            f"{n_events} events and a {ring_size}-slot ring need {need} "
+            f"bytes of SMEM, over the {SMEM_LIMIT_BYTES} the replay kernel "
+            f"may fill")
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return jax.jit(pl.pallas_call(
         functools.partial(_replay_kernel, n_events=n_events,
                           order_op=order_op),
         out_shape=jax.ShapeDtypeStruct((6,), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((ring_size,), jnp.int32)],
+        in_specs=[smem, smem, smem],
+        out_specs=smem,
+        scratch_shapes=[pltpu.SMEM((ring_size,), jnp.int32)],
         interpret=interpret,
     ))
 
@@ -208,7 +234,7 @@ class _VmemReplay(ChannelLowering):
         if order_v:
             raise OrderViolation(
                 trace.channel,
-                f"{order_v} pop(s) the {self.lowering!r} VMEM ring cannot "
+                f"{order_v} pop(s) the {self.lowering!r} ring cannot "
                 f"serve (pop order violates the structure's discipline)")
         if ovf or mism or unf:
             raise RingOverflow(

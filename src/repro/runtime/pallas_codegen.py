@@ -3,9 +3,8 @@
 This module holds the *program* side of the ``"pallas"`` backend
 (`runtime/pallas_backend.py` holds the per-channel trace-replay
 implementations and registers both into the lowering registry).  It
-generalizes the hand-written idiom of `repro.kernels.stencil_fifo.kernel`:
-a time-tiled band stencil whose iteration space is blocked along one
-*streamed* spatial axis, with the dependences crossing the block boundary —
+generates the paper's Fig. 3 idiom for any band stencil: a time-tiled
+stencil whose iteration space is blocked along one *streamed* spatial axis, with the dependences crossing the block boundary —
 the channels the paper's SPLIT isolates at each depth — carried in a VMEM
 scratch ring across the *sequential* Pallas grid.  In-block dependences
 never leave VMEM/VREGs; the addressable-buffer fallback round-trips the
@@ -13,9 +12,9 @@ whole array per timestep instead (the FPGA FIFO-vs-buffer saving, restated
 for the TPU memory hierarchy).
 
 The generated geometry, for a stencil of radius ``r`` along the streamed
-axis (items are scalars for jacobi-1d, rows for jacobi-2d, planes for
-heat-3d; the skew is ``r`` cells per time step so tile writes stay
-block-aligned):
+axis (items are cells for jacobi-1d, carried on the lane axis; rows for
+jacobi-2d, on the sublane axis; planes for heat-3d; the skew is ``r``
+cells per time step so tile writes stay block-aligned):
 
 * ring level ``t`` holds the trailing ``2r`` items of the global item
   stream at time level ``t`` — block ``j`` deposits them, block ``j+1``
@@ -28,7 +27,11 @@ block-aligned):
 * blocks need ``r·steps ≡ 0 (mod block)`` so the skewed final row is
   block-aligned; ``r·steps / block`` extra flush blocks drain the tail.
   ``block = 1`` (the degenerate 1×…×1 tiling) is supported: the trailing
-  halo then accumulates across several predecessor blocks.
+  halo then accumulates across several predecessor blocks.  Compiled for
+  a TPU, the block must fill whole lanes (jacobi-1d: a multiple of 128) or
+  whole sublane rows (jacobi-2d: a multiple of 8), and the ring must fit
+  `VMEM_LIMIT_BYTES`; `CompiledStencil` refuses other geometries by name
+  before lowering.
 
 `compile_analysis` is the `Analysis.compile(backend="pallas")` entry point:
 it reads the `.plan()` records, picks the VMEM-ring mode iff every planned
@@ -40,6 +43,7 @@ analysis cannot derive).
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Optional, Tuple
 
@@ -124,7 +128,7 @@ def _lazy_ref(module: str, fn: str):
 STENCIL_PROGRAMS: Dict[str, StencilProgram] = {
     "jacobi-1d": StencilProgram(
         "jacobi-1d", radius=1, inner_rank=0, update=_jacobi1d_update,
-        ref=_lazy_ref("repro.kernels.stencil_fifo.ref", "jacobi_1d"),
+        ref=_lazy_ref("repro.kernels.stencil_bands.ref", "jacobi_1d"),
         notes="3-point average; items are cells (paper Fig. 1/3)"),
     "jacobi-2d": StencilProgram(
         "jacobi-2d", radius=1, inner_rank=1, update=_jacobi2d_update,
@@ -139,11 +143,33 @@ STENCIL_PROGRAMS: Dict[str, StencilProgram] = {
 
 # -------------------------------------------------------- fused ring kernel --
 
+#: VMEM the generated kernels claim (`vmem_limit_bytes`): half of a TPU v5e
+#: core's 128 MiB, leaving the rest to the compiler's own scratch.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+_SUBLANES, _LANES = 8, 128
+
+
+def _vmem_bytes(shape: Tuple[int, ...]) -> int:
+    """Bytes an f32 array of ``shape`` takes in VMEM, whose two minor
+    dimensions are padded to the (8, 128) tile."""
+    shape = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    sub = -(-shape[-2] // _SUBLANES) * _SUBLANES
+    lane = -(-shape[-1] // _LANES) * _LANES
+    return 4 * math.prod(shape[:-2]) * sub * lane
+
+
+def _check_vmem(what: str, need: int) -> None:
+    if need > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"{what} needs ~{need / 2**20:.1f} MiB of VMEM, over the "
+            f"{VMEM_LIMIT_BYTES / 2**20:.0f} MiB the kernel may claim")
+
+
 def _ring_kernel(x_ref, o_ref, ring_old, ring_new, *, block: int, steps: int,
                  nblocks: int, radius: int, halo: int, ring_depth: int,
-                 n_items: int, inner: Tuple[int, ...], update: Callable):
-    """One grid step = one block of the streamed axis; the FIFO ring carries
-    each time level's trailing ``halo`` items to the next block."""
+                 n_items: int, axis: int, update: Callable):
+    """One grid step = one block of the streamed ``axis``; the FIFO ring
+    carries each time level's trailing ``halo`` items to the next block."""
     j = pl.program_id(0)
 
     # left of the domain is Dirichlet-zero: initialize the ring at block 0
@@ -155,26 +181,34 @@ def _ring_kernel(x_ref, o_ref, ring_old, ring_new, *, block: int, steps: int,
     row = jnp.where(j < nblocks, x_ref[...], jnp.zeros_like(x_ref[...]))
 
     # item index of row position s at time level t is  j·block − r·t + s
-    ids = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
-    ids = ids.reshape((block,) + (1,) * len(inner))
+    ids = jax.lax.broadcasted_iota(
+        jnp.int32, tuple(block if d == axis else 1 for d in range(row.ndim)),
+        axis)
 
-    # depth-0 ring level: trailing halo of the global stream at t=0 (for
-    # block < halo the trailing window spans predecessors — accumulate)
-    ring_new[0] = jnp.concatenate([ring_old[0], row], axis=0)[-halo:]
+    def trailing(level, new):
+        """The trailing ``halo`` items of the stream ending with ``new`` (for
+        block < halo the window spans predecessor blocks — accumulate)."""
+        if block < halo:
+            new = jnp.concatenate([level, new], axis=axis)
+        n = new.shape[axis]
+        return jax.lax.slice_in_dim(new, n - halo, n, axis=axis)
+
+    ring_new[0] = trailing(ring_old[0], row)
 
     def time_step(t, row):
-        left = ring_old[(t - 1) % ring_depth]          # (halo,) + inner
-        prev_full = jnp.concatenate([left, row], axis=0)
+        left = ring_old[(t - 1) % ring_depth]          # halo items
+        prev_full = jnp.concatenate([left, row], axis=axis)
         if halo < 2 * radius:     # injected narrow halo: the missing items
-            gone = jnp.zeros((2 * radius - halo,) + inner, row.dtype)
-            prev_full = jnp.concatenate([gone, prev_full], axis=0)  # are GONE
+            gone = list(left.shape)                    # are GONE
+            gone[axis] = 2 * radius - halo
+            prev_full = jnp.concatenate(
+                [jnp.zeros(gone, row.dtype), prev_full], axis=axis)
         new_row = update(*[jax.lax.slice_in_dim(prev_full, k, k + block,
-                                                axis=0)
+                                                axis=axis)
                            for k in range(2 * radius + 1)])
         idx = j * block - radius * t + ids
         new_row = jnp.where((idx >= 0) & (idx < n_items), new_row, 0.0)
-        ring_new[t % ring_depth] = jnp.concatenate(
-            [ring_old[t % ring_depth], new_row], axis=0)[-halo:]
+        ring_new[t % ring_depth] = trailing(ring_old[t % ring_depth], new_row)
         return new_row
 
     row = jax.lax.fori_loop(1, steps + 1, time_step, row, unroll=False)
@@ -187,20 +221,23 @@ def _ring_kernel(x_ref, o_ref, ring_old, ring_new, *, block: int, steps: int,
     ring_old[...] = ring_new[...]
 
 
-def _addressable_step(x: jnp.ndarray, *, radius: int, update: Callable,
-                      interpret: bool) -> jnp.ndarray:
+def _addressable_step(x: jnp.ndarray, *, radius: int, axis: int,
+                      update: Callable, interpret: bool) -> jnp.ndarray:
     """One time step as its own pallas_call over the WHOLE array — the
     addressable-buffer fallback: every step writes the full level back to
     HBM and reads it again (the paper's reorder-buffer cost model)."""
 
     def kernel(x_ref, o_ref):
         a = x_ref[...]
-        shifts = [_shift_inner(a, 0, radius - k) for k in range(2 * radius + 1)]
+        shifts = [_shift_inner(a, axis, radius - k)
+                  for k in range(2 * radius + 1)]
         o_ref[...] = update(*shifts)
 
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(x)
 
@@ -216,6 +253,12 @@ class CompiledStencil:
     (or a narrower halo than ``2·radius``) produces a kernel whose output
     provably diverges from the oracle — an undersized ring *fails*, it does
     not degrade gracefully.
+
+    The streamed axis is axis 0 of the input.  A 1-D stream (jacobi-1d)
+    rides the lane axis, as ``(1, n)``; a rank-2 input streams rows along
+    the sublane axis.  Compiled for a TPU, a geometry the tiling or VMEM
+    cannot hold raises ValueError before lowering; the interpreter has
+    neither limit.
     """
 
     program: StencilProgram
@@ -239,18 +282,25 @@ class CompiledStencil:
                          self.interpret if interpret is None else interpret)
         p = self.program
         x = x.astype(jnp.float32)
-        if self.mode == "addressable":
-            step = functools.partial(_addressable_step, radius=p.radius,
-                                     update=p.update, interpret=interpret)
-            a = x
-            for _ in range(steps):      # deliberately NOT fused: one kernel
-                a = step(a)             # launch + full-array round trip per t
-            return a
-        n_items = x.shape[0]
-        inner = x.shape[1:]
-        if len(inner) != p.inner_rank:
+        if len(x.shape) != p.inner_rank + 1:
             raise ValueError(f"{p.name}: expected rank {p.inner_rank + 1} "
                              f"input, got shape {x.shape}")
+        lanes = p.inner_rank == 0
+        a = x.reshape(1, -1) if lanes else x
+        axis = 1 if lanes else 0
+        if self.mode == "addressable":
+            if not interpret:
+                # input and output whole, plus the shifted copies in flight
+                # (v5e compiler: 2560² f32 fits the limit, 2816² does not)
+                _check_vmem(f"{p.name} addressable step over {x.shape}",
+                            5 * _vmem_bytes(a.shape) // 2)
+            step = functools.partial(_addressable_step, radius=p.radius,
+                                     axis=axis, update=p.update,
+                                     interpret=interpret)
+            for _ in range(steps):      # deliberately NOT fused: one kernel
+                a = step(a)             # launch + full-array round trip per t
+            return a.reshape(x.shape)
+        n_items = x.shape[0]
         if n_items % block:
             raise ValueError(f"n_items {n_items} % block {block} != 0")
         if (p.radius * steps) % block:
@@ -261,28 +311,53 @@ class CompiledStencil:
         flush = (p.radius * steps) // block
         depth = steps + 1 if ring_depth is None else ring_depth
         h = 2 * p.radius if halo is None else halo
-        blk = (block,) + inner
+        blk = (1, block) if lanes else (block,) + x.shape[1:]
+        level = tuple(h if d == axis else s for d, s in enumerate(blk))
+        if not interpret:
+            self._check_tiling(blk, axis)
+            ext = tuple(s + 2 * p.radius if d == axis else s
+                        for d, s in enumerate(blk))
+            _check_vmem(
+                f"{p.name} ring of {depth} levels over blocks {blk}",
+                2 * _vmem_bytes((depth,) + level) + 4 * _vmem_bytes(blk)
+                + (2 * p.radius + 3) * _vmem_bytes(ext))
+
+        def at(i):
+            return tuple(i if d == axis else 0 for d in range(len(blk)))
 
         out = pl.pallas_call(
             functools.partial(
                 _ring_kernel, block=block, steps=steps, nblocks=nblocks,
                 radius=p.radius, halo=h, ring_depth=depth, n_items=n_items,
-                inner=inner, update=p.update),
+                axis=axis, update=p.update),
             grid=(nblocks + flush,),
             in_specs=[pl.BlockSpec(
-                blk, lambda j: (jnp.minimum(j, nblocks - 1),)
-                + (0,) * len(inner))],
-            out_specs=pl.BlockSpec(
-                blk, lambda j: (jnp.maximum(j - flush, 0),)
-                + (0,) * len(inner)),
-            out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
+                blk, lambda j: at(jnp.minimum(j, nblocks - 1)))],
+            out_specs=pl.BlockSpec(blk, lambda j: at(jnp.maximum(j - flush,
+                                                                 0))),
+            out_shape=jax.ShapeDtypeStruct(a.shape, jnp.float32),
             scratch_shapes=[
-                pltpu.VMEM((depth, h) + inner, jnp.float32),  # ring (read)
-                pltpu.VMEM((depth, h) + inner, jnp.float32),  # ring (write)
+                pltpu.VMEM((depth,) + level, jnp.float32),  # ring (read)
+                pltpu.VMEM((depth,) + level, jnp.float32),  # ring (write)
             ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
-        )(x)
-        return out
+        )(a)
+        return out.reshape(x.shape)
+
+    def _check_tiling(self, blk: Tuple[int, ...], axis: int) -> None:
+        """The TPU tiling rules a compiled block must meet: the streamed
+        block fills whole lanes (1-D) or whole sublane rows (rank 2)."""
+        name, block = self.program.name, blk[axis]
+        if axis == len(blk) - 1 and block % _LANES:
+            raise ValueError(
+                f"{name} streams along the lane axis: block {block} must be "
+                f"a multiple of {_LANES} when compiled for a TPU")
+        if axis == len(blk) - 2 and block % _SUBLANES:
+            raise ValueError(
+                f"{name} streams along the sublane axis: block {block} must "
+                f"be a multiple of {_SUBLANES} when compiled for a TPU")
 
     def describe(self) -> str:
         return (f"CompiledStencil[{self.program.name}] mode={self.mode} "

@@ -9,23 +9,20 @@ import numpy as np
 import pytest
 
 SCRIPT = r"""
-import contextlib
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from repro.optim.compression import (compressed_grad_sync,
                                      compressed_psum_mean,
                                      init_error_feedback)
-from repro.comm.pipeline import _shard_map
 
-# tolerate jax versions without AxisType / set_mesh / jax.shard_map
-if hasattr(jax.sharding, "AxisType"):
-    mesh = jax.make_mesh((4,), ("dp",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-else:
-    mesh = jax.make_mesh((4,), ("dp",))
-use_mesh = getattr(jax, "set_mesh", None) or contextlib.nullcontext
+mesh = jax.make_mesh((4,), ("dp",), axis_types=(jax.sharding.AxisType.Auto,))
+use_mesh = jax.set_mesh
 from jax.sharding import PartitionSpec as P
+
+def _shard_map(fn, mesh, in_specs, out_specs):
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 # --- property: compressed mean ≈ exact mean within quantization bound
 def sync(g, e):

@@ -269,6 +269,33 @@ def test_worker_bad_kernel_yields_error_points():
     assert all(r["error"]["type"] == "KeyError" for r in results)
 
 
+def test_measure_failure_is_an_error_point():
+    """A generated kernel that cannot compile makes its point an error
+    result: jacobi-1d compiled for a TPU refuses a 2-cell lane block."""
+    exp = tiny_experiment(kernels=["jacobi-1d"], tile_sizes=[2],
+                          size_count=1,
+                          measure={"kernels": ["jacobi-1d"],
+                                   "interpret": False, "max_points": 1})
+    results = run_group(exp.groups()[0].as_dict())
+    assert results[0]["error"]["type"] == "ValueError"
+    assert "lane axis" in results[0]["error"]["message"]
+    assert "measured" not in results[0]
+
+
+def test_measure_runs_inline_on_a_tpu_host(monkeypatch):
+    """One process per chip: on a TPU host an experiment that measures
+    refuses the worker managers, and runs inline."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    exp = tiny_experiment(measure={"kernels": ["jacobi-1d"]})
+    with tempfile.TemporaryDirectory() as d:
+        for name in ("pool", "subprocess", "slurm"):
+            with pytest.raises(ValueError, match="inline"):
+                DSEService(exp, ArtifactStore(d), manager=name)
+        DSEService(exp, ArtifactStore(d), manager="inline")
+        DSEService(tiny_experiment(), ArtifactStore(d), manager="pool")
+
+
 def test_manager_registry():
     with pytest.raises(ValueError, match="unknown execution manager"):
         make_manager("carrier-pigeon")

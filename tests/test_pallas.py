@@ -7,7 +7,7 @@
    `test_runtime` holds identically on this backend (positive and
    negative), and an undersized ring raises `RingOverflow` — the failure
    the reference backend cannot produce.
-3. Generated fused kernels: numerical parity vs the `kernels/*/ref.py`
+3. Generated fused kernels: numerical parity vs the `kernels/stencil_bands`
    oracles across tile sizes including the degenerate block=1 tiling,
    mode selection from the plan records, and the undersized-ring /
    narrowed-halo injections whose outputs must DIVERGE from the oracle.
@@ -151,14 +151,15 @@ def test_generated_kernel_matches_reference(name):
         assert jnp.allclose(got, want, **ATOL), (name, block)
 
 
-def test_generated_vs_handwritten_jacobi():
-    from repro.kernels.stencil_fifo import jacobi_fifo
-
+@pytest.mark.parametrize("steps,block", [(16, 16), (32, 16)])
+def test_generated_jacobi1d_matches_reference(steps, block):
+    """jacobi-1d streams its cells on the lane axis: more than one flush
+    block (steps = 2·block) drains the skewed tail as well."""
     c = planned("jacobi-1d").compile(backend="pallas", interpret=True)
     x = jnp.asarray(np.random.default_rng(1).standard_normal(64), jnp.float32)
-    got = c(x, 16, 16)
-    hand = jacobi_fifo(x, steps=16, block=16, interpret=True)
-    assert jnp.allclose(got, hand, **ATOL)
+    got = c(x, steps, block)
+    assert got.shape == x.shape
+    assert jnp.allclose(got, c.program.ref(x, steps), **ATOL)
 
 
 def test_undersized_generated_ring_diverges():
