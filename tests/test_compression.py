@@ -70,5 +70,5 @@ def test_compressed_sync_on_dp_group():
     env["PYTHONPATH"] = str(pathlib.Path(__file__).resolve().parents[1] / "src")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                         capture_output=True, text=True, timeout=420)
+                         capture_output=True, text=True, timeout=240)
     assert "COMPRESSION_OK" in out.stdout, out.stdout + out.stderr

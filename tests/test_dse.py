@@ -354,7 +354,8 @@ def test_sweep_parallel_survives_bad_job():
 
 def _cli(args, env):
     return subprocess.run([sys.executable, "-m", "repro.dse"] + list(args),
-                          env=env, capture_output=True, text=True)
+                          env=env, capture_output=True, text=True,
+                          timeout=240)
 
 
 @pytest.mark.slow
