@@ -165,6 +165,31 @@ def _check_vmem(what: str, need: int) -> None:
             f"{VMEM_LIMIT_BYTES / 2**20:.0f} MiB the kernel may claim")
 
 
+def _shifted_windows(left, row, *, radius: int, axis: int):
+    """The previous level shifted by ``-radius … +radius`` along ``axis``:
+    window ``k`` is items ``k … k + block − 1`` of ``left ++ row``, the
+    ring's halo items then the block.  Each is built from ``left``'s tail
+    and ``row``'s head, never sliced out of the joined value: on the
+    sublane axis that value would start ``2r`` sublanes into a tile, and
+    every window cut from it would span two vregs per tile of ``row``."""
+    block, halo = row.shape[axis], left.shape[axis]
+    if halo < 2 * radius:     # injected narrow halo: the missing items
+        gone = list(left.shape)                        # are GONE
+        gone[axis] = 2 * radius - halo
+        left = jnp.concatenate([jnp.zeros(gone, row.dtype), left], axis=axis)
+        halo = 2 * radius
+    wins = []
+    for k in range(2 * radius + 1):
+        if k == halo:                 # the block itself
+            wins.append(row)
+            continue
+        tail = jax.lax.slice_in_dim(left, k, min(halo, k + block), axis=axis)
+        m = k + block - halo          # the window's items that are row's
+        wins.append(tail if m <= 0 else jnp.concatenate(
+            [tail, jax.lax.slice_in_dim(row, 0, m, axis=axis)], axis=axis))
+    return wins
+
+
 def _ring_kernel(x_ref, o_ref, ring_old, ring_new, *, block: int, steps: int,
                  nblocks: int, radius: int, halo: int, ring_depth: int,
                  n_items: int, axis: int, update: Callable):
@@ -197,15 +222,8 @@ def _ring_kernel(x_ref, o_ref, ring_old, ring_new, *, block: int, steps: int,
 
     def time_step(t, row):
         left = ring_old[(t - 1) % ring_depth]          # halo items
-        prev_full = jnp.concatenate([left, row], axis=axis)
-        if halo < 2 * radius:     # injected narrow halo: the missing items
-            gone = list(left.shape)                    # are GONE
-            gone[axis] = 2 * radius - halo
-            prev_full = jnp.concatenate(
-                [jnp.zeros(gone, row.dtype), prev_full], axis=axis)
-        new_row = update(*[jax.lax.slice_in_dim(prev_full, k, k + block,
-                                                axis=axis)
-                           for k in range(2 * radius + 1)])
+        new_row = update(*_shifted_windows(left, row, radius=radius,
+                                           axis=axis))
         idx = j * block - radius * t + ids
         new_row = jnp.where((idx >= 0) & (idx < n_items), new_row, 0.0)
         ring_new[t % ring_depth] = trailing(ring_old[t % ring_depth], new_row)

@@ -162,19 +162,55 @@ def test_generated_jacobi1d_matches_reference(steps, block):
     assert jnp.allclose(got, c.program.ref(x, steps), **ATOL)
 
 
-def test_undersized_generated_ring_diverges():
-    """Compiling the ring with fewer levels than steps+1 (or a narrower halo
-    than 2·radius) must corrupt the output — the negative direction of the
-    generated-kernel path."""
-    c = planned("jacobi-1d").compile(backend="pallas", interpret=True)
+#: (kernel, shape, steps, block)
+EXACT_CASES = [
+    ("jacobi-2d", (64, 200), 16, 8),
+    ("jacobi-2d", (48, 130), 8, 8),     # width not 128·k
+    ("jacobi-2d", (64, 256), 32, 16),
+    ("jacobi-2d", (16, 8), 4, 4),
+    ("jacobi-2d", (16, 8), 4, 2),       # window 0 is the halo alone
+    ("jacobi-2d", (16, 8), 4, 1),       # block < halo
+    ("heat-3d", (16, 12, 12), 8, 8),
+    ("jacobi-1d", (64,), 8, 8),
+]
+
+
+@pytest.mark.parametrize(
+    "name,shape,steps,block", EXACT_CASES,
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_ring_kernel_is_bitwise_the_reference(name, shape, steps, block):
+    """The ring kernel and the oracle sum the same terms in the same order,
+    so however the shifted windows are built the output is equal to the
+    last bit, not merely close."""
+    c = planned(name).compile(backend="pallas", interpret=True)
+    x = jnp.asarray(np.random.default_rng(4).standard_normal(shape),
+                    jnp.float32)
+    assert np.array_equal(c(x, steps, block), c.program.ref(x, steps))
+
+
+def _assert_undersized_ring_diverges(name, shape):
+    c = planned(name).compile(backend="pallas", interpret=True)
     steps = block = 8
-    x = jnp.asarray(np.random.default_rng(2).standard_normal(64), jnp.float32)
+    x = jnp.asarray(np.random.default_rng(2).standard_normal(shape),
+                    jnp.float32)
     want = c.program.ref(x, steps)
     assert jnp.allclose(c(x, steps, block), want, **ATOL)
     bad_depth = c(x, steps, block, ring_depth=(steps + 1) // 2)
     assert not jnp.allclose(bad_depth, want, **ATOL)
     bad_halo = c(x, steps, block, halo=2 * c.program.radius - 1)
     assert not jnp.allclose(bad_halo, want, **ATOL)
+
+
+def test_undersized_generated_ring_diverges():
+    """Compiling the ring with fewer levels than steps+1 (or a narrower halo
+    than 2·radius) must corrupt the output — the negative direction of the
+    generated-kernel path."""
+    _assert_undersized_ring_diverges("jacobi-1d", (64,))
+
+
+def test_undersized_generated_ring_diverges_on_rows():
+    """The same on jacobi-2d, whose rows stream on the sublane axis."""
+    _assert_undersized_ring_diverges("jacobi-2d", (32, 16))
 
 
 def test_compile_mode_follows_the_plans():

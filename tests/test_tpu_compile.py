@@ -5,10 +5,15 @@ the kernels `chip_smoke.py` runs are compiled here at its shapes: the
 generated ring kernels, the addressable fallback and the SMEM trace-replay
 kernel.  Each geometry the compiler would refuse must instead raise a named
 error before lowering.  The topology is described inside a fixture, never
-on import: only one process may load the TPU compiler at a time.
+on import: only one process may load the TPU compiler at a time.  One test
+reads Mosaic's own dump of the ring kernel, made by a process of its own
+before this one loads the compiler, and bounds the rotates its time-step
+loop spends.
 """
 import os
 import pathlib
+import re
+import subprocess
 import sys
 
 import jax
@@ -16,7 +21,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
 
 import repro.core.polybench  # noqa: E402,F401  (populate the registry)
 from chip_smoke import COMPILER_CASES  # noqa: E402
@@ -25,8 +31,48 @@ from repro.core.registry import get  # noqa: E402
 from repro.runtime import pallas_backend as pb  # noqa: E402
 
 
+#: compiles the jacobi-2d ring kernel at (64, 256), 8 steps, block 8, for a
+#: described v5e, in a process of its own so that libtpu reads the Mosaic
+#: dump flag at its start
+_DUMP_SCRIPT = """
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+import repro.core.polybench
+from repro.core import analyze
+from repro.core.registry import get
+jax.config.update("jax_enable_compilation_cache", False)
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+c = (analyze(get("jacobi-2d")).classify().fifoize().size().plan()
+     .compile(backend="pallas"))
+x = jax.ShapeDtypeStruct((64, 256), jnp.float32,
+                         sharding=SingleDeviceSharding(topo.devices[0]))
+jax.jit(lambda a: c(a, 8, 8, interpret=False)).lower(x).compile()
+"""
+
+
 @pytest.fixture(scope="module")
-def topo():
+def ring_dump(tmp_path_factory):
+    """Mosaic's passes over the jacobi-2d ring kernel, compiled for a
+    described v5e by a process of its own: libtpu reads its dump flag only
+    when a process loads it, and one process at a time may hold it, so this
+    runs before `topo` loads it here."""
+    dump = tmp_path_factory.mktemp("mosaic")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
+               PYTHONPATH=str(ROOT / "src"),
+               LIBTPU_INIT_ARGS=" ".join(filter(None, [
+                   os.environ.get("LIBTPU_INIT_ARGS"),
+                   f"--xla_mosaic_dump_to={dump}"])))
+    try:
+        out = subprocess.run([sys.executable, "-c", _DUMP_SCRIPT], env=env,
+                             capture_output=True, text=True, timeout=240)
+    except subprocess.TimeoutExpired as slow:   # fails the one test that
+        out = slow                              # reads the dump, no other
+    return dump, out
+
+
+@pytest.fixture(scope="module")
+def topo(ring_dump):
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
@@ -94,3 +140,37 @@ def test_uncompilable_geometry_is_refused_by_name(one_chip, name, shape,
 def test_trace_too_long_for_smem_is_refused(one_chip):
     with pytest.raises(pb.TraceTooLong, match="SMEM"):
         pb._replay_call(1 << 17, 16, pb._FIFO, False)
+
+
+def _ring_loop_body(dump_dir: pathlib.Path):
+    """The time-step loop of the ring kernel after Mosaic's vector layout
+    pass: its out vregs (the loop's carried values) and its body's lines."""
+    path, = dump_dir.glob("*_ring_kernel-post-apply-vector-layout-simplify*")
+    lines = path.read_text().splitlines()
+    start = next(i for i, line in enumerate(lines) if "scf.for" in line)
+    carried = int(re.search(r"%\w+:(\d+) = scf.for", lines[start]).group(1))
+    depth, body = 0, []
+    for line in lines[start:]:
+        depth += line.count("{") - line.count("}")
+        body.append(line)
+        if depth <= 0:
+            break
+    return carried, body
+
+
+def test_ring_windows_cost_few_rotates_per_vreg(topo, ring_dump):
+    """jacobi-2d streams rows on the sublane axis: built from the halo's
+    tail and the block's head, each shifted window costs one sublane rotate
+    per vreg, and ``update`` runs on the block's own vregs.  Built as slices
+    of one (2 + 8)-row concatenation it cost 10 non-zero rotates per out
+    vreg (7 on sublanes, 3 on lanes); now 4 (2 and 2)."""
+    dump, out = ring_dump
+    assert not isinstance(out, subprocess.TimeoutExpired), out
+    assert out.returncode == 0, out.stderr[-4000:]
+    out_vregs, body = _ring_loop_body(dump)
+    assert out_vregs == 2                     # 8 rows x 256 lanes
+    rotates = [m.groups() for line in body for m in re.finditer(
+        r"tpu\.rotate \S+ by (\d+) dim (\d+)", line) if m.group(1) != "0"]
+    sublane = sum(dim == "0" for _, dim in rotates)
+    assert sublane <= 2 * out_vregs, rotates
+    assert len(rotates) <= 5 * out_vregs, rotates
