@@ -47,3 +47,33 @@ def heat_3d(a0: jnp.ndarray, steps: int) -> jnp.ndarray:
                 + 0.125 * (p[1:-1, :-2, 1:-1] - 2.0 * c + p[1:-1, 2:, 1:-1])
                 + 0.125 * (p[1:-1, 1:-1, :-2] - 2.0 * c + p[1:-1, 1:-1, 2:]))
     return _iterate(step, a0, steps)
+
+
+def seidel_2d(a0: jnp.ndarray, steps: int) -> jnp.ndarray:
+    """T in-place sweeps of the 9-point Gauss-Seidel average, rows in order
+    (i then j, as PolyBench's loop nest):
+    a[i,j] ← (a[i−1,j−1] + a[i−1,j] + a[i−1,j+1] + a[i,j−1] + a[i,j]
+              + a[i,j+1] + a[i+1,j−1] + a[i+1,j] + a[i+1,j+1]) / 9,
+    where row i−1 and a[i,j−1] already hold this sweep's values.  Each row
+    is a first-order recurrence along j, x_j = (c_j + x_{j−1}) / 9 with c
+    the other eight terms, computed as an associative scan of the affine
+    maps x ↦ x/9 + c_j/9."""
+    def compose(f, g):                    # g applied after f
+        return f[0] * g[0], g[0] * f[1] + g[1]
+
+    def row(prev, old):                   # prev: new row i−1; old: rows i, i+1
+        up, mid, down = jnp.pad(prev, 1), jnp.pad(old[0], 1), \
+            jnp.pad(old[1], 1)
+        c = (up[:-2] + up[1:-1] + up[2:] + mid[1:-1] + mid[2:]
+             + down[:-2] + down[1:-1] + down[2:])
+        _, x = jax.lax.associative_scan(
+            compose, (jnp.full_like(c, 1.0 / 9.0), c / 9.0))
+        return x, x
+
+    def step(a):
+        below = jnp.concatenate([a[1:], jnp.zeros_like(a[:1])])
+        _, rows = jax.lax.scan(row, jnp.zeros_like(a[0]),
+                               jnp.stack([a, below], axis=1))
+        return rows
+    with jax.default_matmul_precision("highest"):
+        return _iterate(step, a0, steps)

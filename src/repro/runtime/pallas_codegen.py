@@ -13,12 +13,17 @@ for the TPU memory hierarchy).
 
 The generated geometry, for a stencil of radius ``r`` along the streamed
 axis (items are cells for jacobi-1d, carried on the lane axis; rows for
-jacobi-2d, on the sublane axis; planes for heat-3d; the skew is ``r``
-cells per time step so tile writes stay block-aligned):
+jacobi-2d and seidel-2d, on the sublane axis; planes for heat-3d; the skew
+is ``r`` items per time step so tile writes stay block-aligned):
 
-* ring level ``t`` holds the trailing ``2r`` items of the global item
+* ring level ``t`` holds the trailing ``halo`` items of the global item
   stream at time level ``t`` — block ``j`` deposits them, block ``j+1``
-  consumes them;
+  consumes them.  A Jacobi program computes level ``t`` from level
+  ``t − 1`` alone, items up to ``2r`` positions back: ``halo = 2r``.  An
+  in-place (Gauss-Seidel) program reads the ``r`` items before its own at
+  level ``t`` and items ``0 … +r`` of level ``t − 1``, which lie up to
+  ``r`` positions back: ``halo = r``, and a block takes its items of one
+  level in stream order;
 * the ring has ``steps + 1`` levels; levels are addressed modulo
   ``ring_depth`` (default ``steps + 1``), so an *undersized* ring is a real
   ring-capacity failure (level ``t`` is clobbered before the next block
@@ -29,7 +34,7 @@ cells per time step so tile writes stay block-aligned):
   ``block = 1`` (the degenerate 1×…×1 tiling) is supported: the trailing
   halo then accumulates across several predecessor blocks.  Compiled for
   a TPU, the block must fill whole lanes (jacobi-1d: a multiple of 128) or
-  whole sublane rows (jacobi-2d: a multiple of 8), and the ring must fit
+  whole sublane rows (rank 2: a multiple of 8), and the ring must fit
   `VMEM_LIMIT_BYTES`; `CompiledStencil` refuses other geometries by name
   before lowering.
 
@@ -66,11 +71,17 @@ def default_interpret() -> bool:
 @dataclass(frozen=True)
 class StencilProgram:
     """The semantic half of a band-stencil kernel: what one time step
-    computes.  ``update`` receives ``2·radius + 1`` arrays — the previous
-    time level shifted by ``-radius … +radius`` along the streamed axis,
-    each of shape ``(block,) + inner`` — and returns the new level.  Inner
-    (non-streamed) axes are full-width; their boundary handling lives
-    inside ``update`` (Dirichlet-zero, matching the `ref` oracle)."""
+    computes.  ``update`` receives ``2·radius + 1`` arrays — the item
+    stream shifted by ``-radius … +radius`` along the streamed axis — and
+    returns the new items.  For a Jacobi program all of them are the
+    previous time level, each of shape ``(block,) + inner``.  An
+    ``in_place`` program sweeps in stream order, as Gauss-Seidel does: the
+    ``radius`` inputs before the item are already at the level being
+    computed, the rest still at the previous one, and ``update`` gets one
+    item at a time, each input of shape ``(1,) + inner``.  Inner
+    (non-streamed) axes are full-width; their boundary handling, and any
+    same-level dependence along them, lives inside ``update``
+    (Dirichlet-zero, matching the `ref` oracle)."""
 
     name: str                                  # registry kernel it mirrors
     radius: int                                # dependence radius, streamed axis
@@ -78,6 +89,31 @@ class StencilProgram:
     update: Callable[..., jnp.ndarray]
     ref: Callable[[jnp.ndarray, int], jnp.ndarray]   # pure-jnp oracle
     notes: str = ""
+    in_place: bool = False
+    #: the ``pallas_call``'s name, which the device trace shows; None keeps
+    #: JAX's default, the name of the jitted caller
+    trace_name: Optional[str] = None
+
+    @property
+    def halo(self) -> int:
+        """Items of each level that a block hands the next: how far back of
+        its own position an item reads (the skew is ``radius`` a level)."""
+        return self.radius if self.in_place else 2 * self.radius
+
+    def dependent_steps(self, shape: Tuple[int, ...], steps: int,
+                        block: int) -> int:
+        """The longest chain of dependent vector steps in one call of the
+        generated ring kernel over ``shape``: grid steps (``shape[0] /
+        block`` blocks and ``radius·steps / block`` flush blocks) × ``steps``
+        time steps × the serial depth of one time step.  A Jacobi time step
+        is one step: the block's items at once.  An in-place one takes the
+        block's items in order, each one stencil sum and then the
+        `lane_scan_levels` of its lane recurrence over the width ``W =
+        shape[-1]``: ``block · (1 + ⌈log2 W⌉)``."""
+        grid = (shape[0] + self.radius * steps) // block
+        depth = block * (1 + lane_scan_levels(shape[-1])) \
+            if self.in_place else 1
+        return grid * steps * depth
 
 
 def _shift_inner(a: jnp.ndarray, axis: int, off: int) -> jnp.ndarray:
@@ -116,6 +152,34 @@ def _heat3d_update(up, center, down):
             + 0.125 * (kl - 2.0 * center + kr))
 
 
+def lane_scan_levels(width: int) -> int:
+    """Levels of a log-step scan across ``width`` lanes: shifts of 1, 2, 4,
+    … lanes reach ``2**levels − 1 ≥ width − 1`` lanes back."""
+    return (width - 1).bit_length()
+
+
+def _lane_recurrence(c: jnp.ndarray, decay: float) -> jnp.ndarray:
+    """``x_j = c_j + decay·x_{j−1}`` along the last axis, ``x_{−1} = 0``,
+    as a log-step (Hillis–Steele) scan over the whole width: after level
+    ``d`` each ``x_j`` holds ``Σ_{m < 2^(d+1)} decay^m c_{j−m}``.  Every
+    level runs, whatever ``decay^(2^d)`` rounds to in float32, so the code
+    leaves no term out."""
+    x = c
+    for d in range(lane_scan_levels(c.shape[-1])):
+        x = x + decay ** (2 ** d) * _shift_inner(x, -1, 2 ** d)
+    return x
+
+
+def _seidel2d_update(up, center, down):
+    """Row i from row i−1 at the new level (``up``) and rows i, i+1 at the
+    previous one: the 9-point average, whose ``A[i][j−1]`` is new too, so
+    ``x_j = (c_j + x_{j−1}) / 9`` with ``c`` the other eight terms."""
+    c = (_shift_inner(up, -1, +1) + up + _shift_inner(up, -1, -1)
+         + center + _shift_inner(center, -1, -1)
+         + _shift_inner(down, -1, +1) + down + _shift_inner(down, -1, -1))
+    return _lane_recurrence(c / 9.0, 1.0 / 9.0)
+
+
 def _lazy_ref(module: str, fn: str):
     def call(a0, steps):
         import importlib
@@ -138,6 +202,12 @@ STENCIL_PROGRAMS: Dict[str, StencilProgram] = {
         "heat-3d", radius=1, inner_rank=2, update=_heat3d_update,
         ref=_lazy_ref("repro.kernels.stencil_bands.ref", "heat_3d"),
         notes="7-point star; items are planes, (j,k) stream inside"),
+    "seidel-2d": StencilProgram(
+        "seidel-2d", radius=1, inner_rank=1, update=_seidel2d_update,
+        ref=_lazy_ref("repro.kernels.stencil_bands.ref", "seidel_2d"),
+        notes="9-point Gauss-Seidel average in place; items are rows, "
+              "j recurs along the lanes",
+        in_place=True, trace_name="seidel_2d_ring"),
 }
 
 
@@ -165,21 +235,30 @@ def _check_vmem(what: str, need: int) -> None:
             f"{VMEM_LIMIT_BYTES / 2**20:.0f} MiB the kernel may claim")
 
 
-def _shifted_windows(left, row, *, radius: int, axis: int):
-    """The previous level shifted by ``-radius … +radius`` along ``axis``:
-    window ``k`` is items ``k … k + block − 1`` of ``left ++ row``, the
-    ring's halo items then the block.  Each is built from ``left``'s tail
-    and ``row``'s head, never sliced out of the joined value: on the
-    sublane axis that value would start ``2r`` sublanes into a tile, and
-    every window cut from it would span two vregs per tile of ``row``."""
+def _gone(left, reach: int, axis: int):
+    """``left`` with zero items in front up to ``reach`` items: what an
+    injected narrow halo leaves out is GONE."""
+    halo = left.shape[axis]
+    if halo >= reach:
+        return left
+    gone = list(left.shape)
+    gone[axis] = reach - halo
+    return jnp.concatenate([jnp.zeros(gone, left.dtype), left], axis=axis)
+
+
+def _shifted_windows(left, row, *, reach: int, axis: int):
+    """The previous level shifted back by ``reach … 0`` items along
+    ``axis`` (a Jacobi program's offsets ``-r … +r``, ``reach = 2r``; an
+    in-place one's ``0 … +r``, ``reach = r``): window ``k`` is items ``k …
+    k + block − 1`` of ``left ++ row``, the ring's halo items then the
+    block.  Each is built from ``left``'s tail and ``row``'s head, never
+    sliced out of the joined value: on the sublane axis that value would
+    start ``reach`` sublanes into a tile, and every window cut from it
+    would span two vregs per tile of ``row``."""
+    left = _gone(left, reach, axis)
     block, halo = row.shape[axis], left.shape[axis]
-    if halo < 2 * radius:     # injected narrow halo: the missing items
-        gone = list(left.shape)                        # are GONE
-        gone[axis] = 2 * radius - halo
-        left = jnp.concatenate([jnp.zeros(gone, row.dtype), left], axis=axis)
-        halo = 2 * radius
     wins = []
-    for k in range(2 * radius + 1):
+    for k in range(reach + 1):
         if k == halo:                 # the block itself
             wins.append(row)
             continue
@@ -190,11 +269,31 @@ def _shifted_windows(left, row, *, radius: int, axis: int):
     return wins
 
 
-def _ring_kernel(x_ref, o_ref, ring_old, ring_new, *, block: int, steps: int,
-                 nblocks: int, radius: int, halo: int, ring_depth: int,
-                 n_items: int, axis: int, update: Callable):
+def _in_stream_order(before, wins, keep, *, radius: int, axis: int,
+                     update: Callable):
+    """One level of an in-place program, its items in stream order: item
+    ``s`` reads the ``radius`` items before it at this level — first the
+    ring's (``before``, left there by the previous block), then its own
+    predecessors' — and items ``s … s + radius`` of the previous level
+    (``wins``).  An item outside the domain is zero before its successor
+    reads it."""
+    done = [jax.lax.slice_in_dim(before, k, k + 1, axis=axis)
+            for k in range(radius)]
+    for s in range(keep.shape[axis]):
+        item = update(*done[-radius:], *(
+            jax.lax.slice_in_dim(w, s, s + 1, axis=axis) for w in wins))
+        done.append(jnp.where(jax.lax.slice_in_dim(keep, s, s + 1,
+                                                   axis=axis), item, 0.0))
+    return jnp.concatenate(done[radius:], axis=axis)
+
+
+def _ring_kernel(x_ref, o_ref, ring_old, ring_new, *, program: StencilProgram,
+                 block: int, steps: int, nblocks: int, halo: int,
+                 ring_depth: int, n_items: int, axis: int):
     """One grid step = one block of the streamed ``axis``; the FIFO ring
-    carries each time level's trailing ``halo`` items to the next block."""
+    carries each time level's trailing ``halo`` items to the next block
+    (fewer than ``program.halo`` is an injected narrow halo)."""
+    radius, update = program.radius, program.update
     j = pl.program_id(0)
 
     # left of the domain is Dirichlet-zero: initialize the ring at block 0
@@ -220,12 +319,21 @@ def _ring_kernel(x_ref, o_ref, ring_old, ring_new, *, block: int, steps: int,
 
     ring_new[0] = trailing(ring_old[0], row)
 
+    def in_domain(t):
+        idx = j * block - radius * t + ids
+        return (idx >= 0) & (idx < n_items)
+
     def time_step(t, row):
         left = ring_old[(t - 1) % ring_depth]          # halo items
-        new_row = update(*_shifted_windows(left, row, radius=radius,
-                                           axis=axis))
-        idx = j * block - radius * t + ids
-        new_row = jnp.where((idx >= 0) & (idx < n_items), new_row, 0.0)
+        wins = _shifted_windows(left, row, reach=program.halo, axis=axis)
+        if program.in_place:
+            before = _gone(ring_old[t % ring_depth], radius, axis)
+            new_row = _in_stream_order(before, wins, in_domain(t),
+                                       radius=radius, axis=axis,
+                                       update=update)
+        else:
+            new_row = update(*wins)
+            new_row = jnp.where(in_domain(t), new_row, 0.0)
         ring_new[t % ring_depth] = trailing(ring_old[t % ring_depth], new_row)
         return new_row
 
@@ -287,9 +395,9 @@ class CompiledStencil:
     diagnostics: Dict[str, object] = field(default_factory=dict)
 
     def ring_slots(self, steps: int) -> int:
-        """Items held in one ring buffer: (steps+1) levels × 2r per level
-        (each item is one channel value of inner shape)."""
-        return (steps + 1) * 2 * self.program.radius
+        """Items held in one ring buffer: (steps+1) levels × the program's
+        halo per level (each item is one channel value of inner shape)."""
+        return (steps + 1) * self.program.halo
 
     def __call__(self, x: jnp.ndarray, steps: int, block: int,
                  interpret: Optional[bool] = None,
@@ -328,26 +436,26 @@ class CompiledStencil:
         nblocks = n_items // block
         flush = (p.radius * steps) // block
         depth = steps + 1 if ring_depth is None else ring_depth
-        h = 2 * p.radius if halo is None else halo
+        h = p.halo if halo is None else halo
         blk = (1, block) if lanes else (block,) + x.shape[1:]
         level = tuple(h if d == axis else s for d, s in enumerate(blk))
         if not interpret:
             self._check_tiling(blk, axis)
-            ext = tuple(s + 2 * p.radius if d == axis else s
+            ext = tuple(s + p.halo if d == axis else s
                         for d, s in enumerate(blk))
             _check_vmem(
                 f"{p.name} ring of {depth} levels over blocks {blk}",
                 2 * _vmem_bytes((depth,) + level) + 4 * _vmem_bytes(blk)
-                + (2 * p.radius + 3) * _vmem_bytes(ext))
+                + (p.halo + 3) * _vmem_bytes(ext))
 
         def at(i):
             return tuple(i if d == axis else 0 for d in range(len(blk)))
 
         out = pl.pallas_call(
             functools.partial(
-                _ring_kernel, block=block, steps=steps, nblocks=nblocks,
-                radius=p.radius, halo=h, ring_depth=depth, n_items=n_items,
-                axis=axis, update=p.update),
+                _ring_kernel, program=p, block=block, steps=steps,
+                nblocks=nblocks, halo=h, ring_depth=depth, n_items=n_items,
+                axis=axis),
             grid=(nblocks + flush,),
             in_specs=[pl.BlockSpec(
                 blk, lambda j: at(jnp.minimum(j, nblocks - 1)))],
@@ -361,6 +469,7 @@ class CompiledStencil:
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
+            name=p.trace_name,
         )(a)
         return out.reshape(x.shape)
 
@@ -426,6 +535,11 @@ def compile_analysis(analysis, mode: Optional[str] = None,
             f".fifoize() first, or compile mode='addressable')")
     if mode not in ("fifo-ring", "addressable"):
         raise ValueError(f"unknown mode {mode!r}")
+    if mode == "addressable" and program.in_place:
+        raise ValueError(
+            f"{name}: the addressable mode has no in-place step — its "
+            f"whole-array kernel would sweep {name} as Jacobi does; only "
+            f"the fifo-ring mode runs an in-place program")
     return CompiledStencil(
         program=program, mode=mode, plans=tuple(analysis.plans),
         kernel_name=name, interpret=interpret,

@@ -135,6 +135,7 @@ GEOMETRIES = {
     "jacobi-1d": ((32,), 4, (1, 2, 4)),
     "jacobi-2d": ((16, 8), 4, (1, 4)),
     "heat-3d": ((8, 4, 4), 2, (1, 2)),
+    "seidel-2d": ((16, 20), 8, (1, 4, 8)),     # width not 128·k
 }
 
 
@@ -197,7 +198,7 @@ def _assert_undersized_ring_diverges(name, shape):
     assert jnp.allclose(c(x, steps, block), want, **ATOL)
     bad_depth = c(x, steps, block, ring_depth=(steps + 1) // 2)
     assert not jnp.allclose(bad_depth, want, **ATOL)
-    bad_halo = c(x, steps, block, halo=2 * c.program.radius - 1)
+    bad_halo = c(x, steps, block, halo=c.program.halo - 1)
     assert not jnp.allclose(bad_halo, want, **ATOL)
 
 
@@ -211,6 +212,112 @@ def test_undersized_generated_ring_diverges():
 def test_undersized_generated_ring_diverges_on_rows():
     """The same on jacobi-2d, whose rows stream on the sublane axis."""
     _assert_undersized_ring_diverges("jacobi-2d", (32, 16))
+
+
+def test_undersized_in_place_ring_diverges():
+    """The same on seidel-2d, whose halo is one row (``radius``, not
+    ``2·radius``): a ring of half the levels, or no halo at all, loses the
+    row that the next block reads at both levels."""
+    assert STENCIL_PROGRAMS["seidel-2d"].halo == 1
+    _assert_undersized_ring_diverges("seidel-2d", (32, 20))
+
+
+# ------------------------------------------------- in-place (seidel-2d) ----
+
+
+def _seidel_loop(a, steps, dtype):
+    """PolyBench's loop nest in ``dtype``: i then j, in place, every cell of
+    the array updated, zero outside it (the repo's spec)."""
+    p = np.zeros((a.shape[0] + 2, a.shape[1] + 2), dtype)
+    p[1:-1, 1:-1] = a
+    nine = dtype(9.0)
+    for _ in range(steps):
+        for i in range(1, a.shape[0] + 1):
+            for j in range(1, a.shape[1] + 1):
+                p[i, j] = (p[i - 1, j - 1] + p[i - 1, j] + p[i - 1, j + 1]
+                           + p[i, j - 1] + p[i, j] + p[i, j + 1]
+                           + p[i + 1, j - 1] + p[i + 1, j]
+                           + p[i + 1, j + 1]) / nine
+    return p[1:-1, 1:-1]
+
+
+def _bench_seidel_step():
+    import importlib.util
+    import pathlib
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench"
+            / "configs" / "seidel-2d.py")
+    spec = importlib.util.spec_from_file_location("bench_seidel_2d", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.step
+
+
+def _bench_seidel(a0, steps):
+    step = _bench_seidel_step()
+    a = a0
+    for _ in range(steps):
+        a = step(a)
+    return a
+
+
+#: a float32 reference against the loop: the scan regroups each row's
+#: recurrence as products of powers of 1/9, in another order than the
+#: loop's nine-term sums, so equality to the last bit is not expected; a
+#: few float32 ulps of the terms (magnitude ≤ ~3, ulp ≈ 2.4e-7) part them,
+#: and against the float64 loop the references' own rounding adds as much
+SEIDEL_ATOL = 2e-6
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.float32],
+                         ids=["float64", "float32"])
+@pytest.mark.parametrize("shape", [(6, 6), (9, 13)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("which", ["oracle", "bench"])
+def test_seidel_references_match_polybench_loop(which, shape, dtype):
+    steps = 3
+    a = np.random.default_rng(7).standard_normal(shape)
+    want = _seidel_loop(a.astype(dtype), steps, dtype)
+    ref = (STENCIL_PROGRAMS["seidel-2d"].ref if which == "oracle"
+           else _bench_seidel)
+    got = np.asarray(ref(jnp.asarray(a, jnp.float32), steps))
+    np.testing.assert_allclose(got, want, rtol=0, atol=SEIDEL_ATOL)
+
+
+def test_lane_recurrence_spans_the_whole_width():
+    """With decay 1 the recurrence is a running sum, whose first term
+    weighs as much on the last lane as on the second: a scan cut to a
+    window of lanes would lose it.  Widths on both sides of a power of
+    two."""
+    from repro.runtime.pallas_codegen import (_lane_recurrence,
+                                              lane_scan_levels)
+    assert [lane_scan_levels(w) for w in (1, 2, 3, 128, 129, 4000)] == \
+        [0, 1, 2, 7, 8, 12]
+    for width in (5, 128, 129, 4000):
+        c = np.random.default_rng(width).integers(-4, 5, (2, width))
+        got = _lane_recurrence(jnp.asarray(c, jnp.float32), 1.0)
+        assert np.array_equal(np.asarray(got), np.cumsum(c, axis=1)), width
+
+
+def test_dependent_steps_counts_the_chain():
+    """Grid steps × time steps × one time step's serial depth: 1 for a
+    Jacobi program, block · (1 + ⌈log2 W⌉) for an in-place one."""
+    jacobi, seidel = STENCIL_PROGRAMS["jacobi-2d"], STENCIL_PROGRAMS["seidel-2d"]
+    assert jacobi.dependent_steps((2800, 2800), 200, 8) == (350 + 25) * 200
+    assert seidel.dependent_steps((4000, 4000), 200, 8) == \
+        (500 + 25) * 200 * 8 * (1 + 12)
+    assert seidel.dependent_steps((16, 20), 8, 1) == (16 + 8) * 8 * 1 * 6
+
+
+def test_in_place_plan_selects_the_ring_and_refuses_addressable():
+    """seidel-2d's compute channels are all planned as cheap FIFOs, so the
+    plan alone selects the ring; the addressable step has no in-place form
+    and refuses by name rather than sweep it as Jacobi does."""
+    a = planned("seidel-2d")
+    c = a.compile(backend="pallas")
+    assert c.mode == "fifo-ring" and c.diagnostics["reorder_plans"] == []
+    assert c.diagnostics["compute_plans"] == 16
+    with pytest.raises(ValueError, match="seidel-2d.*addressable.*in-place"):
+        a.compile(backend="pallas", mode="addressable")
 
 
 def test_compile_mode_follows_the_plans():

@@ -103,16 +103,27 @@ def _compile_stencil(one_chip, name, shape, steps, block, mode=None):
     return fn.lower(x).compile().as_text()
 
 
-#: the smoke's cases, and heat-3d at the deepest ring its VMEM estimate
-#: admits (the compiler's limit lies between T=200 and T=240)
+#: the smoke's cases, heat-3d at the deepest ring its VMEM estimate
+#: admits (the compiler's limit lies between T=200 and T=240), and the
+#: in-place seidel-2d ring at its benchmark cell's geometry
 @pytest.mark.parametrize(
     "name,shape,steps,block,mode",
-    COMPILER_CASES + (("heat-3d", (128, 128, 128), 200, 8, None),),
+    COMPILER_CASES + (("heat-3d", (128, 128, 128), 200, 8, None),
+                      ("seidel-2d", (4000, 4000), 200, 8, None)),
     ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
 def test_stencil_kernel_compiles_for_v5e(one_chip, name, shape, steps, block,
                                          mode):
     assert "tpu_custom_call" in _compile_stencil(one_chip, name, shape, steps,
                                                  block, mode)
+
+
+def test_in_place_kernel_carries_its_name(one_chip):
+    """The device trace names a kernel by its HLO instruction: seidel-2d's
+    is its program's ``trace_name``, and jacobi-2d keeps JAX's default."""
+    assert "%seidel_2d_ring" in _compile_stencil(one_chip, "seidel-2d",
+                                                 (64, 256), 8, 8)
+    assert "%_lambda_" in _compile_stencil(one_chip, "jacobi-2d",
+                                           (64, 256), 8, 8)
 
 
 @pytest.mark.parametrize("n_events,ring,order", [
