@@ -19,11 +19,17 @@ is ``r`` items per time step so tile writes stay block-aligned):
 * ring level ``t`` holds the trailing ``halo`` items of the global item
   stream at time level ``t`` — block ``j`` deposits them, block ``j+1``
   consumes them.  A Jacobi program computes level ``t`` from level
-  ``t − 1`` alone, items up to ``2r`` positions back: ``halo = 2r``.  An
+  ``t − 1`` alone, items up to ``2r`` positions back: ``halo = 2r``, and a
+  block takes ``steps`` time steps, the whole block at one level each.  An
   in-place (Gauss-Seidel) program reads the ``r`` items before its own at
   level ``t`` and items ``0 … +r`` of level ``t − 1``, which lie up to
-  ``r`` positions back: ``halo = r``, and a block takes its items of one
-  level in stream order;
+  ``r`` positions back: ``halo = r``.  For ``r = 1`` those are, in the
+  block's skewed positions, item ``s − 1`` at ``t`` and items ``s − 1``
+  and ``s`` at ``t − 1``, all on earlier anti-diagonals ``s + t``: so a
+  block takes ``steps + block − 1`` wavefronts, item ``s`` going to level
+  ``w − s`` in wavefront ``w``, and its rows share the sublanes.  The
+  kernel is written for that geometry alone, rows on the sublane axis at
+  radius 1;
 * the ring has ``steps + 1`` levels; levels are addressed modulo
   ``ring_depth`` (default ``steps + 1``), so an *undersized* ring is a real
   ring-capacity failure (level ``t`` is clobbered before the next block
@@ -77,8 +83,9 @@ class StencilProgram:
     previous time level, each of shape ``(block,) + inner``.  An
     ``in_place`` program sweeps in stream order, as Gauss-Seidel does: the
     ``radius`` inputs before the item are already at the level being
-    computed, the rest still at the previous one, and ``update`` gets one
-    item at a time, each input of shape ``(1,) + inner``.  Inner
+    computed, the rest still at the previous one.  Its ``update`` gets a
+    block's diagonal, each input of shape ``(block,) + inner`` with every
+    item at its own level, so it must not mix items.  Inner
     (non-streamed) axes are full-width; their boundary handling, and any
     same-level dependence along them, lives inside ``update``
     (Dirichlet-zero, matching the `ref` oracle)."""
@@ -103,17 +110,19 @@ class StencilProgram:
     def dependent_steps(self, shape: Tuple[int, ...], steps: int,
                         block: int) -> int:
         """The longest chain of dependent vector steps in one call of the
-        generated ring kernel over ``shape``: grid steps (``shape[0] /
-        block`` blocks and ``radius·steps / block`` flush blocks) × ``steps``
-        time steps × the serial depth of one time step.  A Jacobi time step
-        is one step: the block's items at once.  An in-place one takes the
-        block's items in order, each one stencil sum and then the
-        `lane_scan_levels` of its lane recurrence over the width ``W =
-        shape[-1]``: ``block · (1 + ⌈log2 W⌉)``."""
+        generated ring kernel over ``shape``, over its grid steps
+        (``shape[0] / block`` blocks and ``radius·steps / block`` flush
+        blocks).  A Jacobi grid step is ``steps`` time steps of one vector
+        step each: the block's items at once.  An in-place one is ``steps +
+        block − 1`` wavefronts, each one stencil sum and then the
+        `lane_scan_levels` of the lane recurrence over the width ``W =
+        shape[-1]``: ``(steps + block − 1) · (1 + ⌈log2 W⌉)``.  Of a
+        wavefront's items, ``steps / (steps + block − 1)`` do work on
+        average; the rest wait for the diagonal to reach or leave them."""
         grid = (shape[0] + self.radius * steps) // block
-        depth = block * (1 + lane_scan_levels(shape[-1])) \
-            if self.in_place else 1
-        return grid * steps * depth
+        if not self.in_place:
+            return grid * steps
+        return grid * (steps + block - 1) * (1 + lane_scan_levels(shape[-1]))
 
 
 def _shift_inner(a: jnp.ndarray, axis: int, off: int) -> jnp.ndarray:
@@ -269,24 +278,6 @@ def _shifted_windows(left, row, *, reach: int, axis: int):
     return wins
 
 
-def _in_stream_order(before, wins, keep, *, radius: int, axis: int,
-                     update: Callable):
-    """One level of an in-place program, its items in stream order: item
-    ``s`` reads the ``radius`` items before it at this level — first the
-    ring's (``before``, left there by the previous block), then its own
-    predecessors' — and items ``s … s + radius`` of the previous level
-    (``wins``).  An item outside the domain is zero before its successor
-    reads it."""
-    done = [jax.lax.slice_in_dim(before, k, k + 1, axis=axis)
-            for k in range(radius)]
-    for s in range(keep.shape[axis]):
-        item = update(*done[-radius:], *(
-            jax.lax.slice_in_dim(w, s, s + 1, axis=axis) for w in wins))
-        done.append(jnp.where(jax.lax.slice_in_dim(keep, s, s + 1,
-                                                   axis=axis), item, 0.0))
-    return jnp.concatenate(done[radius:], axis=axis)
-
-
 def _ring_kernel(x_ref, o_ref, ring_old, ring_new, *, program: StencilProgram,
                  block: int, steps: int, nblocks: int, halo: int,
                  ring_depth: int, n_items: int, axis: int):
@@ -326,18 +317,38 @@ def _ring_kernel(x_ref, o_ref, ring_old, ring_new, *, program: StencilProgram,
     def time_step(t, row):
         left = ring_old[(t - 1) % ring_depth]          # halo items
         wins = _shifted_windows(left, row, reach=program.halo, axis=axis)
-        if program.in_place:
-            before = _gone(ring_old[t % ring_depth], radius, axis)
-            new_row = _in_stream_order(before, wins, in_domain(t),
-                                       radius=radius, axis=axis,
-                                       update=update)
-        else:
-            new_row = update(*wins)
-            new_row = jnp.where(in_domain(t), new_row, 0.0)
+        new_row = update(*wins)
+        new_row = jnp.where(in_domain(t), new_row, 0.0)
         ring_new[t % ring_depth] = trailing(ring_old[t % ring_depth], new_row)
         return new_row
 
-    row = jax.lax.fori_loop(1, steps + 1, time_step, row, unroll=False)
+    def wavefront(w, state):
+        """Wavefront ``w`` of an in-place program (radius 1): item ``s``
+        goes to level ``t = w − s`` where ``1 ≤ t ≤ steps``.  It reads the
+        item before it at ``t`` (``cur``, one item on, the ring's level
+        ``w`` in front), its own at ``t − 1`` (``prev``, after wavefront
+        ``w − 2``, likewise with level ``w − 1``) and the one after it at
+        ``t − 1`` (``cur`` as it is): all on earlier wavefronts, so the
+        block's items go at once."""
+        cur, prev = state
+        up = _shifted_windows(ring_old[w % ring_depth], cur, reach=radius,
+                              axis=axis)[0]
+        center = _shifted_windows(ring_old[(w - 1) % ring_depth], prev,
+                                  reach=radius, axis=axis)[0]
+        t = w - ids
+        new = jnp.where(in_domain(t), update(up, center, cur), 0.0)
+        new = jnp.where((t >= 1) & (t <= steps), new, cur)
+        # the block's last item reaches level w − block + 1; before level 1
+        # it still holds level 0, which level 0's slot already has
+        ring_new[jnp.maximum(w - block + 1, 0) % ring_depth] = \
+            jax.lax.slice_in_dim(new, block - halo, block, axis=axis)
+        return new, cur
+
+    if program.in_place:
+        row, _ = jax.lax.fori_loop(1, steps + block, wavefront, (row, row),
+                                   unroll=False)
+    else:
+        row = jax.lax.fori_loop(1, steps + 1, time_step, row, unroll=False)
 
     # block j's final row covers items [(j − flush)·block, …); early blocks
     # write a dummy block 0 that block `flush` overwrites
@@ -426,6 +437,11 @@ class CompiledStencil:
             for _ in range(steps):      # deliberately NOT fused: one kernel
                 a = step(a)             # launch + full-array round trip per t
             return a.reshape(x.shape)
+        if p.in_place and (p.radius != 1 or lanes):
+            raise ValueError(
+                f"{p.name}: the in-place ring kernel runs its wavefronts on "
+                f"rows along the sublane axis at radius 1; got radius "
+                f"{p.radius}{', items on the lane axis' if lanes else ''}")
         n_items = x.shape[0]
         if n_items % block:
             raise ValueError(f"n_items {n_items} % block {block} != 0")

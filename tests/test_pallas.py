@@ -23,6 +23,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+jax = pytest.importorskip("jax")
 jnp = pytest.importorskip("jax.numpy")
 
 import repro.core.polybench  # noqa: F401,E402  (populate the registry)
@@ -299,13 +300,73 @@ def test_lane_recurrence_spans_the_whole_width():
 
 
 def test_dependent_steps_counts_the_chain():
-    """Grid steps × time steps × one time step's serial depth: 1 for a
-    Jacobi program, block · (1 + ⌈log2 W⌉) for an in-place one."""
+    """Grid steps × the serial depth of one: ``steps`` for a Jacobi
+    program, ``(steps + block − 1)`` wavefronts of ``1 + ⌈log2 W⌉`` for an
+    in-place one."""
     jacobi, seidel = STENCIL_PROGRAMS["jacobi-2d"], STENCIL_PROGRAMS["seidel-2d"]
     assert jacobi.dependent_steps((2800, 2800), 200, 8) == (350 + 25) * 200
     assert seidel.dependent_steps((4000, 4000), 200, 8) == \
-        (500 + 25) * 200 * 8 * (1 + 12)
+        (500 + 25) * 207 * 13
     assert seidel.dependent_steps((16, 20), 8, 1) == (16 + 8) * 8 * 1 * 6
+
+
+def _row_order_replay(update, a, steps):
+    """An in-place program's levels with each level's rows in order: row
+    ``i`` from the new row above and the old rows ``i`` and ``i + 1``, zero
+    outside the array."""
+    update = jax.jit(update)
+    zero = jnp.zeros((1, a.shape[1]), jnp.float32)
+    rows = [a[i:i + 1] for i in range(a.shape[0])] + [zero]
+    for _ in range(steps):
+        above = zero
+        for i in range(a.shape[0]):
+            rows[i] = above = update(above, rows[i], rows[i + 1])
+    return jnp.concatenate(rows[:-1])
+
+
+#: (block, steps, width): every block that divides ``steps`` (the skewed
+#: writes stay block-aligned), so one step runs at block 1 alone
+WAVEFRONT_CASES = [(block, steps, width)
+                   for block in (1, 4, 8, 16) for steps in (1, 8, 16)
+                   for width in (20, 130, 256) if steps % block == 0]
+
+
+@pytest.mark.parametrize("block,steps,width", WAVEFRONT_CASES)
+def test_in_place_wavefronts_are_bitwise_the_row_order(block, steps, width):
+    """The ring kernel takes a block's rows along the diagonal wavefront,
+    each row at its own level; every row gets the same update on the same
+    inputs as in row order, so the output is equal to the last bit.
+
+    One step is the exception, and not the schedule's: XLA's CPU compiler
+    removes the kernel's one-trip time loop and then folds the lane scan's
+    constant factors into products of powers of 1/9, which round otherwise
+    (one step runs at block 1 alone, where a wavefront is one row: the row
+    order itself).  That case is held to 2 ulps of the array's largest
+    magnitude: its values near zero are differences of larger terms, so an
+    ulp of each value says nothing."""
+    c = planned("seidel-2d").compile(backend="pallas", interpret=True)
+    x = jnp.asarray(np.random.default_rng(block * width + steps)
+                    .standard_normal((32, width)), jnp.float32)
+    want = np.asarray(_row_order_replay(c.program.update, x, steps))
+    got = np.asarray(c(x, steps, block))
+    if steps == 1:
+        np.testing.assert_allclose(
+            got, want, rtol=0, atol=2 * np.spacing(np.abs(want).max()))
+    else:
+        assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("change,shape", [({"radius": 2}, (16, 20)),
+                                          ({"inner_rank": 0}, (32,))],
+                         ids=["radius-2", "lane-axis"])
+def test_in_place_ring_refuses_other_geometries(change, shape):
+    """The wavefront schedule is written for rows on the sublane axis at
+    radius 1; any other in-place geometry is refused by name."""
+    from repro.runtime.pallas_codegen import CompiledStencil
+    program = dataclasses.replace(STENCIL_PROGRAMS["seidel-2d"], **change)
+    stencil = CompiledStencil(program, "fifo-ring", interpret=True)
+    with pytest.raises(ValueError, match="seidel-2d: the in-place ring"):
+        stencil(jnp.zeros(shape, jnp.float32), 8, 8)
 
 
 def test_in_place_plan_selects_the_ring_and_refuses_addressable():
