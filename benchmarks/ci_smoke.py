@@ -197,7 +197,7 @@ def pallas_smoke(failures: list) -> None:
     t0 = time.perf_counter()
     try:
         a = (analyze(get("jacobi-1d")).classify().fifoize().size().plan())
-        c = a.compile(backend="pallas", interpret=True)
+        c = a.compile(backend="pallas")
         if c.mode != "fifo-ring":
             failures.append(f"pallas: expected fifo-ring mode, got {c.mode}")
         steps = block = 16

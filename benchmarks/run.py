@@ -70,13 +70,12 @@ def smoke(validate: bool = False) -> None:
 
 
 def main() -> None:
-    from . import (fig3_stencil, moe_capacity, pipeline_comm,
-                   roofline_report, table1_storage, table2_fifo)
+    from . import (moe_capacity, pipeline_comm, roofline_report,
+                   table1_storage, table2_fifo)
 
     print("name,us_per_call,derived")
     table2_fifo.main(_emit)      # paper Table 2: FIFO recovery
     table1_storage.main(_emit)   # paper Table 1: storage impact
-    fig3_stencil.main(_emit)     # Fig. 3: the FIFO stencil kernel on TPU terms
     pipeline_comm.main(_emit)    # the planner on pipeline/SP schedules
     moe_capacity.main(_emit)     # capacity-factor → drop-rate ablation
     roofline_report.main(_emit)  # §Roofline summary from the dry-run cache

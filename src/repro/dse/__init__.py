@@ -18,8 +18,7 @@ builds the map:
   polyhedron verdict store, so an interrupted sweep resumes with zero
   recomputation;
 * `worker`      — evaluates one group: template/concrete analysis,
-  lowering-override rewriting, frontier metrics, optional measured pallas
-  kernel time;
+  lowering-override rewriting, frontier metrics;
 * `pareto`      — per-kernel Pareto frontiers over (fifo_fraction,
   total_slots, cost) with dominated-point provenance;
 * `service`     — `DSEService.run/frontier/status`, also the

@@ -141,7 +141,6 @@ class GroupTask:
     override_id: str = "planned"
     size_mode: str = "parametric"          # or "concrete"
     pow2: bool = True
-    measure: Optional[Mapping[str, Any]] = None   # pallas timing request
 
     def points(self) -> List[DesignPoint]:
         return [DesignPoint(self.kernel, self.tiling_id, self.tiling,
@@ -158,9 +157,7 @@ class GroupTask:
                 "overrides": (None if self.overrides is None
                               else dict(self.overrides)),
                 "override_id": self.override_id,
-                "size_mode": self.size_mode, "pow2": self.pow2,
-                "measure": (None if self.measure is None
-                            else dict(self.measure))}
+                "size_mode": self.size_mode, "pow2": self.pow2}
 
     @classmethod
     def from_dict(cls, doc: Mapping[str, Any]) -> "GroupTask":
@@ -173,9 +170,7 @@ class GroupTask:
                               else dict(doc["overrides"])),
                    override_id=doc.get("override_id", "planned"),
                    size_mode=doc.get("size_mode", "parametric"),
-                   pow2=bool(doc.get("pow2", True)),
-                   measure=(None if doc.get("measure") is None
-                            else dict(doc["measure"])))
+                   pow2=bool(doc.get("pow2", True)))
 
     def restricted(self, keep_keys) -> "GroupTask":
         """The same task with the size axis restricted to the points whose
@@ -183,8 +178,7 @@ class GroupTask:
         envs = tuple(p.sizes for p in self.points() if p.key in keep_keys)
         return GroupTask(self.task_id, self.kernel, self.tiling_id,
                          self.tiling, self.topology, envs, self.overrides,
-                         self.override_id, self.size_mode, self.pow2,
-                         self.measure)
+                         self.override_id, self.size_mode, self.pow2)
 
 
 # ------------------------------------------------------------- experiment ---
@@ -210,7 +204,6 @@ class Experiment:
     size_mode: Mapping[str, str] = field(
         default_factory=lambda: {"default": "parametric"})
     pow2: bool = True
-    measure: Optional[Mapping[str, Any]] = None
 
     # ------------------------------------------------------------- identity --
     def as_dict(self) -> Dict[str, Any]:
@@ -221,9 +214,7 @@ class Experiment:
                 "sizes": dict(self.sizes),
                 "lowering_overrides": [None if o is None else dict(o)
                                        for o in self.lowering_overrides],
-                "size_mode": dict(self.size_mode), "pow2": self.pow2,
-                "measure": (None if self.measure is None
-                            else dict(self.measure))}
+                "size_mode": dict(self.size_mode), "pow2": self.pow2}
 
     @classmethod
     def from_dict(cls, doc: Mapping[str, Any]) -> "Experiment":
@@ -233,6 +224,9 @@ class Experiment:
                             f"match this build's {SPEC_VERSION}")
         if not doc.get("kernels"):
             raise SpecError("spec needs a non-empty 'kernels' list")
+        if doc.get("measure") is not None:     # older specs carry null
+            raise SpecError("'measure' is no longer a spec field: generated "
+                            "kernels are timed on the chip by bench/")
         return cls(name=doc.get("name", "experiment"),
                    kernels=list(doc["kernels"]),
                    tilings=dict(doc.get("tilings",
@@ -245,9 +239,7 @@ class Experiment:
                        for o in doc.get("lowering_overrides", [None])],
                    size_mode=dict(doc.get("size_mode",
                                           {"default": "parametric"})),
-                   pow2=bool(doc.get("pow2", True)),
-                   measure=(None if doc.get("measure") is None
-                            else dict(doc["measure"])))
+                   pow2=bool(doc.get("pow2", True)))
 
     @property
     def experiment_id(self) -> str:
@@ -320,14 +312,6 @@ class Experiment:
         return self.size_mode.get(kernel,
                                   self.size_mode.get("default", "parametric"))
 
-    def _measure_for(self, kernel: str) -> Optional[Dict[str, Any]]:
-        m = self.measure
-        if not m or kernel not in m.get("kernels", ()):
-            return None
-        return {"repeats": int(m.get("repeats", 1)),
-                "max_points": int(m.get("max_points", 2)),
-                "interpret": m.get("interpret")}
-
     def groups(self, registry_get=None) -> List[GroupTask]:
         """Expand the spec into worker units (deterministic order: kernel,
         tiling, topology, override — the size axis rides inside)."""
@@ -347,8 +331,7 @@ class Experiment:
                             kernel=kernel, tiling_id=tid, tiling=cfg_doc,
                             topology=topo, size_envs=envs,
                             overrides=ov, override_id=oid,
-                            size_mode=self._mode(kernel), pow2=self.pow2,
-                            measure=self._measure_for(kernel)))
+                            size_mode=self._mode(kernel), pow2=self.pow2))
         return out
 
     def points(self, registry_get=None) -> List[DesignPoint]:
@@ -359,9 +342,7 @@ def default_experiment(name: str = "polybench-full",
                        kernels: Optional[Sequence[str]] = None,
                        tile_sizes: Sequence[int] = DEFAULT_TILE_SIZES,
                        topologies: Sequence[str] = ("sequential", "pipeline"),
-                       size_count: int = 3,
-                       measure: Optional[Mapping[str, Any]] = None
-                       ) -> Experiment:
+                       size_count: int = 3) -> Experiment:
     """The acceptance grid: all 15 PolyBench kernels × 12 tilings × 2
     topologies × 3 sizes.  The size axis is lattice-generated except where
     the economics invert: the 2d/3d stencils and doitgen run the size axis
@@ -398,5 +379,4 @@ def default_experiment(name: str = "polybench-full",
                    "jacobi-2d": "concrete", "seidel-2d": "concrete",
                    "heat-3d": "concrete", "doitgen": "concrete",
                    "symm": "concrete", "cholesky": "concrete",
-                   "lu": "concrete"},
-        measure=measure)
+                   "lu": "concrete"})

@@ -7,9 +7,7 @@ The objective space is the paper's communication trade, priced:
 * ``total_slots``     — minimize (aggregate buffer capacity the sizing
   stage allocates — paper Table 1's storage column);
 * ``cost``            — minimize; the roofline prediction
-  (``metrics.predicted_s``) by default, or measured generated-kernel
-  seconds (``metrics.measured_s``) for the measured frontier, restricted
-  to points that have one.
+  (``metrics.predicted_s``) by default.
 
 Dominance is the usual weak-dominance: ``a`` dominates ``b`` iff ``a`` is
 at least as good on every objective and strictly better on one.  Dominated
@@ -104,25 +102,18 @@ def pareto_front(points: Sequence[Mapping[str, Any]],
 
 
 def frontier_by_kernel(points: Sequence[Mapping[str, Any]],
-                       cost_key: str = "predicted_s",
-                       measured: bool = True) -> Dict[str, Any]:
+                       cost_key: str = "predicted_s") -> Dict[str, Any]:
     """Per-kernel frontiers over a whole experiment's result docs: for each
-    kernel the predicted frontier and — where any point carries a measured
-    kernel time — the measured frontier over that subset."""
+    kernel the predicted frontier."""
     by_kernel: Dict[str, List[Mapping[str, Any]]] = {}
     for p in points:
         by_kernel.setdefault(p.get("kernel", "?"), []).append(p)
     out: Dict[str, Any] = {}
     for kernel in sorted(by_kernel):
         pts = by_kernel[kernel]
-        doc: Dict[str, Any] = {"points": len(pts),
-                               "errors": sum(1 for p in pts
-                                             if p.get("error")),
-                               "predicted": pareto_front(pts, cost_key)}
-        if measured and any((p.get("metrics") or {}).get("measured_s")
-                            is not None for p in pts):
-            doc["measured"] = pareto_front(pts, "measured_s")
-        out[kernel] = doc
+        out[kernel] = {"points": len(pts),
+                       "errors": sum(1 for p in pts if p.get("error")),
+                       "predicted": pareto_front(pts, cost_key)}
     return out
 
 
@@ -132,11 +123,9 @@ def frontier_summary(frontiers: Mapping[str, Any]) -> List[str]:
     for kernel, doc in frontiers.items():
         fr = doc["predicted"]["frontier"]
         best = fr[0]["vector"] if fr else None
-        extra = f", measured frontier {len(doc['measured']['frontier'])}" \
-            if "measured" in doc else ""
         lines.append(
             f"{kernel:12s} {doc['points']:4d} points "
             f"({doc['errors']} errors), frontier {len(fr)}"
             + (f", best fifo {best[0]:.2f} @ {int(best[1])} slots"
-               if best else "") + extra)
+               if best else ""))
     return lines

@@ -19,10 +19,6 @@ Every successful point carries:
   channels (the paper's tables count those), ``total_slots`` (whole network)
   and ``compute_slots``, plus the roofline prediction
   (`repro.launch.roofline.predict_report_cost`);
-* ``measured`` — where requested and the pallas backend applies
-  (`STENCIL_PROGRAMS`), wall-clock seconds of the generated kernel
-  (`measure_compiled`) with its geometry; absent otherwise.  A kernel that
-  fails to compile or run makes the point an error result;
 * ``provenance`` — how the number was produced: ``size_mode`` actually used
   per point, fallback reasons, applied lowering overrides, seconds spent.
 """
@@ -88,44 +84,13 @@ def point_metrics(doc: Mapping[str, Any], compute: Tuple[str, ...]
             "roofline": cost}
 
 
-# ------------------------------------------------------------ measurement ---
-
-def _measure_point(kernel_name: str, analysis, sizes: Optional[Mapping],
-                   tiling_cfg, spec: Mapping[str, Any]
-                   ) -> Optional[Dict[str, Any]]:
-    """Time the generated pallas kernel for this point, if the backend
-    applies; None (with no side effects) where it does not."""
-    from ..runtime.pallas_codegen import STENCIL_PROGRAMS
-    if kernel_name not in STENCIL_PROGRAMS:
-        return None
-    from ..runtime.pallas_backend import measure_compiled
-    try:
-        compiled = analysis.compile(backend="pallas",
-                                    interpret=spec.get("interpret"))
-    except ValueError:
-        # reorder-buffer plans force the addressable fallback — measure that
-        compiled = analysis.compile(backend="pallas", mode="addressable",
-                                    interpret=spec.get("interpret"))
-    block = max(int(b) for t in tiling_cfg.values() for b in t.sizes)
-    radius = compiled.program.radius
-    # smallest geometry the kernel accepts around the point's size: steps a
-    # multiple of block/gcd so skewed writes stay aligned, n >= 4 blocks
-    steps = block if (radius * block) % block == 0 else block * radius
-    n = max(int(next(iter(sizes.values()))) if sizes else 4 * block,
-            4 * block)
-    n += (-n) % block
-    return measure_compiled(compiled, n, steps, block,
-                            repeats=int(spec.get("repeats", 1)),
-                            interpret=spec.get("interpret"))
-
-
 # ------------------------------------------------------------- group runs ---
 
 def _evaluate_concrete(kernel, env, cfg, topology, pow2):
     a = (analyze(kernel, params=None if env is None else dict(env),
                  tilings=cfg)
          .classify().fifoize().size(pow2=pow2).plan(topology=topology))
-    return a, report_payload(a.report())
+    return report_payload(a.report())
 
 
 def run_group(task_doc: Mapping[str, Any]) -> List[Dict[str, Any]]:
@@ -171,7 +136,6 @@ def run_group(task_doc: Mapping[str, Any]) -> List[Dict[str, Any]]:
         t0 = time.perf_counter()
         row = point.as_dict()
         try:
-            analysis = None
             mode = "concrete"
             notes: List[str] = []
             if template is not None and point.sizes is not None:
@@ -188,7 +152,7 @@ def run_group(task_doc: Mapping[str, Any]) -> List[Dict[str, Any]]:
                 if task.size_mode == "parametric" and template_note:
                     notes.append(f"template fallback: {template_note}")
                     mode = "concrete-fallback"
-                analysis, doc = _evaluate_concrete(
+                doc = _evaluate_concrete(
                     case.kernel, point.sizes, cfg, task.topology, task.pow2)
             doc, applied = apply_lowering_overrides(doc, task.overrides)
             row["report"] = doc
@@ -198,19 +162,6 @@ def run_group(task_doc: Mapping[str, Any]) -> List[Dict[str, Any]]:
                 "overrides_applied": applied,
                 "template_build_s": round(t_build, 6) if i == 0 else 0.0,
                 "seconds": round(time.perf_counter() - t0, 6)}
-            if task.measure is not None \
-                    and i < int(task.measure.get("max_points", 2)) \
-                    and not applied:            # measured kernel ≡ the plan
-                if analysis is None:            # parametric path has no
-                    analysis, _ = _evaluate_concrete(   # Analysis object
-                        case.kernel, point.sizes, cfg, task.topology,
-                        task.pow2)
-                # a kernel that cannot compile or run is this point's error
-                m = _measure_point(task.kernel, analysis, point.sizes, cfg,
-                                   task.measure)
-                if m is not None:
-                    row["measured"] = m
-                    row["metrics"]["measured_s"] = m["seconds"]
         except Exception as e:
             row["error"] = _error_doc(e)
             row["provenance"] = {

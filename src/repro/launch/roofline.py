@@ -13,9 +13,8 @@ the broadcast register) stay in on-chip scratch, the addressable reorder
 buffer round-trips HBM — and returns the roofline max of the compute and
 memory terms.  It is a *ranking* model (deliberately simple, microseconds to
 evaluate, monotone in the trade the paper makes: losing a FIFO verdict moves
-that channel's bytes from VMEM to HBM), not a simulator; where the pallas
-backend applies, the DSE pairs it with measured generated-kernel time
-(`repro.runtime.pallas_backend.measure_compiled`).
+that channel's bytes from VMEM to HBM), not a simulator.  Generated
+kernels are timed on the chip by the benchmark under ``bench/``.
 """
 import argparse
 import json

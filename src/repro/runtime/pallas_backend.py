@@ -40,7 +40,7 @@ from __future__ import annotations
 import functools
 import heapq
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -211,12 +211,9 @@ class _VmemReplay(ChannelLowering):
 
     order_op: int = _REORDER
 
-    def run(self, trace: ChannelTrace, slots: Optional[int] = None,
-            interpret: Optional[bool] = None) -> int:
+    def run(self, trace: ChannelTrace, slots: Optional[int] = None) -> int:
         if trace.num_edges == 0:
             return 0
-        if interpret is None:
-            interpret = default_interpret()
         ev = _build_events(trace)
         nslots = ev.needed if slots is None else max(1, int(slots))
         n = len(ev.kind)
@@ -227,7 +224,7 @@ class _VmemReplay(ChannelLowering):
         slot = np.concatenate([ev.slot % nslots, np.zeros(pad,
                                                           dtype=np.int64)])
         call = _replay_call(n_pad, _pow2(nslots), self.order_op,
-                            bool(interpret))
+                            default_interpret())
         peak, order_v, mism, ovf, unf = (int(x) for x in np.asarray(
             call(jnp.asarray(kind, jnp.int32), jnp.asarray(value, jnp.int32),
                  jnp.asarray(slot, jnp.int32)))[:5])
@@ -290,46 +287,3 @@ class AddressableVmem(_VmemReplay):
 
 # whole-PPN compiler: Analysis.compile(backend="pallas") resolves here
 PALLAS.compile = compile_analysis
-
-
-# ------------------------------------------------------------ timing hook ---
-
-def measure_compiled(compiled, n_items: int, steps: int, block: int,
-                     repeats: int = 1, interpret: Optional[bool] = None,
-                     seed: int = 0) -> dict:
-    """Wall-clock one compiled stencil (`Analysis.compile(backend="pallas")`)
-    on a concrete geometry: best-of-``repeats`` after a warm-up call, the
-    `bench_pallas` discipline.  This is the DSE's *measured* cost channel —
-    where the pallas backend applies, the Pareto frontier ranks design
-    points by this alongside the roofline prediction.
-
-    Raises ValueError on a geometry the kernel cannot run (`n_items` not a
-    multiple of ``block``, skew misalignment) — callers decide whether to
-    snap the geometry or skip the measurement, but never get a silently
-    different one."""
-    import time
-
-    p = compiled.program
-    if n_items % block:
-        raise ValueError(f"n_items {n_items} % block {block} != 0")
-    if (p.radius * steps) % block:
-        raise ValueError(f"radius*steps ({p.radius * steps}) % block "
-                         f"{block} != 0")
-    shape = (n_items,) + tuple(max(4, block) for _ in range(p.inner_rank))
-    rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-
-    def run():
-        return compiled(x, steps, block, interpret=interpret)
-
-    run().block_until_ready()                     # compile + warm
-    best = float("inf")
-    for _ in range(max(1, repeats)):
-        t0 = time.perf_counter()
-        run().block_until_ready()
-        best = min(best, time.perf_counter() - t0)
-    return {"seconds": best, "mode": compiled.mode,
-            "n_items": n_items, "steps": steps, "block": block,
-            "interpret": bool(default_interpret() if interpret is None
-                              else interpret),
-            "repeats": max(1, repeats)}

@@ -95,7 +95,6 @@ class StencilProgram:
     inner_rank: int                            # rank of one streamed item
     update: Callable[..., jnp.ndarray]
     ref: Callable[[jnp.ndarray, int], jnp.ndarray]   # pure-jnp oracle
-    notes: str = ""
     in_place: bool = False
     #: the ``pallas_call``'s name, which the device trace shows; None keeps
     #: JAX's default, the name of the jitted caller
@@ -201,21 +200,16 @@ def _lazy_ref(module: str, fn: str):
 STENCIL_PROGRAMS: Dict[str, StencilProgram] = {
     "jacobi-1d": StencilProgram(
         "jacobi-1d", radius=1, inner_rank=0, update=_jacobi1d_update,
-        ref=_lazy_ref("repro.kernels.stencil_bands.ref", "jacobi_1d"),
-        notes="3-point average; items are cells (paper Fig. 1/3)"),
+        ref=_lazy_ref("repro.kernels.stencil_bands.ref", "jacobi_1d")),
     "jacobi-2d": StencilProgram(
         "jacobi-2d", radius=1, inner_rank=1, update=_jacobi2d_update,
-        ref=_lazy_ref("repro.kernels.stencil_bands.ref", "jacobi_2d"),
-        notes="5-point average; items are rows, j streams inside"),
+        ref=_lazy_ref("repro.kernels.stencil_bands.ref", "jacobi_2d")),
     "heat-3d": StencilProgram(
         "heat-3d", radius=1, inner_rank=2, update=_heat3d_update,
-        ref=_lazy_ref("repro.kernels.stencil_bands.ref", "heat_3d"),
-        notes="7-point star; items are planes, (j,k) stream inside"),
+        ref=_lazy_ref("repro.kernels.stencil_bands.ref", "heat_3d")),
     "seidel-2d": StencilProgram(
         "seidel-2d", radius=1, inner_rank=1, update=_seidel2d_update,
         ref=_lazy_ref("repro.kernels.stencil_bands.ref", "seidel_2d"),
-        notes="9-point Gauss-Seidel average in place; items are rows, "
-              "j recurs along the lanes",
         in_place=True, trace_name="seidel_2d_ring"),
 }
 
@@ -401,22 +395,13 @@ class CompiledStencil:
     program: StencilProgram
     mode: str
     plans: Tuple = ()
-    kernel_name: str = ""
-    interpret: Optional[bool] = None
     diagnostics: Dict[str, object] = field(default_factory=dict)
-
-    def ring_slots(self, steps: int) -> int:
-        """Items held in one ring buffer: (steps+1) levels × the program's
-        halo per level (each item is one channel value of inner shape)."""
-        return (steps + 1) * self.program.halo
 
     def __call__(self, x: jnp.ndarray, steps: int, block: int,
                  interpret: Optional[bool] = None,
                  ring_depth: Optional[int] = None,
                  halo: Optional[int] = None) -> jnp.ndarray:
-        interpret = (default_interpret() if interpret is None
-                     else interpret) if self.interpret is None else (
-                         self.interpret if interpret is None else interpret)
+        interpret = default_interpret() if interpret is None else interpret
         p = self.program
         x = x.astype(jnp.float32)
         if len(x.shape) != p.inner_rank + 1:
@@ -518,8 +503,7 @@ def _memory_channels(analysis) -> frozenset:
                      if mem(ch.producer) or mem(ch.consumer))
 
 
-def compile_analysis(analysis, mode: Optional[str] = None,
-                     interpret: Optional[bool] = None) -> CompiledStencil:
+def compile_analysis(analysis, mode: Optional[str] = None) -> CompiledStencil:
     """The pallas backend's `Backend.compile` hook.
 
     Requires a `.plan()` stage: the ChannelPlan records decide the mode —
@@ -558,7 +542,6 @@ def compile_analysis(analysis, mode: Optional[str] = None,
             f"the fifo-ring mode runs an in-place program")
     return CompiledStencil(
         program=program, mode=mode, plans=tuple(analysis.plans),
-        kernel_name=name, interpret=interpret,
         diagnostics={"cheap_plans": sum(p.is_cheap for p in compute_plans),
                      "compute_plans": len(compute_plans),
                      "memory_plans": len(memory),

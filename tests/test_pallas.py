@@ -143,7 +143,7 @@ GEOMETRIES = {
 @pytest.mark.parametrize("name", sorted(GEOMETRIES))
 def test_generated_kernel_matches_reference(name):
     shape, steps, blocks = GEOMETRIES[name]
-    c = planned(name).compile(backend="pallas", interpret=True)
+    c = planned(name).compile(backend="pallas")
     assert c.mode == "fifo-ring", c.describe()
     x = jnp.asarray(np.random.default_rng(0).standard_normal(shape),
                     jnp.float32)
@@ -157,7 +157,7 @@ def test_generated_kernel_matches_reference(name):
 def test_generated_jacobi1d_matches_reference(steps, block):
     """jacobi-1d streams its cells on the lane axis: more than one flush
     block (steps = 2·block) drains the skewed tail as well."""
-    c = planned("jacobi-1d").compile(backend="pallas", interpret=True)
+    c = planned("jacobi-1d").compile(backend="pallas")
     x = jnp.asarray(np.random.default_rng(1).standard_normal(64), jnp.float32)
     got = c(x, steps, block)
     assert got.shape == x.shape
@@ -184,14 +184,14 @@ def test_ring_kernel_is_bitwise_the_reference(name, shape, steps, block):
     """The ring kernel and the oracle sum the same terms in the same order,
     so however the shifted windows are built the output is equal to the
     last bit, not merely close."""
-    c = planned(name).compile(backend="pallas", interpret=True)
+    c = planned(name).compile(backend="pallas")
     x = jnp.asarray(np.random.default_rng(4).standard_normal(shape),
                     jnp.float32)
     assert np.array_equal(c(x, steps, block), c.program.ref(x, steps))
 
 
 def _assert_undersized_ring_diverges(name, shape):
-    c = planned(name).compile(backend="pallas", interpret=True)
+    c = planned(name).compile(backend="pallas")
     steps = block = 8
     x = jnp.asarray(np.random.default_rng(2).standard_normal(shape),
                     jnp.float32)
@@ -344,7 +344,7 @@ def test_in_place_wavefronts_are_bitwise_the_row_order(block, steps, width):
     order itself).  That case is held to 2 ulps of the array's largest
     magnitude: its values near zero are differences of larger terms, so an
     ulp of each value says nothing."""
-    c = planned("seidel-2d").compile(backend="pallas", interpret=True)
+    c = planned("seidel-2d").compile(backend="pallas")
     x = jnp.asarray(np.random.default_rng(block * width + steps)
                     .standard_normal((32, width)), jnp.float32)
     want = np.asarray(_row_order_replay(c.program.update, x, steps))
@@ -364,7 +364,7 @@ def test_in_place_ring_refuses_other_geometries(change, shape):
     radius 1; any other in-place geometry is refused by name."""
     from repro.runtime.pallas_codegen import CompiledStencil
     program = dataclasses.replace(STENCIL_PROGRAMS["seidel-2d"], **change)
-    stencil = CompiledStencil(program, "fifo-ring", interpret=True)
+    stencil = CompiledStencil(program, "fifo-ring")
     with pytest.raises(ValueError, match="seidel-2d: the in-place ring"):
         stencil(jnp.zeros(shape, jnp.float32), 8, 8)
 
@@ -398,7 +398,7 @@ def test_compile_mode_follows_the_plans():
     bad = dataclasses.replace(victim, lowering=REORDER_BUFFER)
     forced = dataclasses.replace(
         a, plans=tuple(bad if p.name == victim.name else p for p in a.plans))
-    c = forced.compile(backend="pallas", interpret=True)
+    c = forced.compile(backend="pallas")
     assert c.mode == "addressable"
     assert c.diagnostics["reorder_plans"] == [victim.name]
     with pytest.raises(ValueError, match="reorder"):
