@@ -60,6 +60,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -96,6 +97,10 @@ class StencilProgram:
     update: Callable[..., jnp.ndarray]
     ref: Callable[[jnp.ndarray, int], jnp.ndarray]   # pure-jnp oracle
     in_place: bool = False
+    #: the decay of an in-place program's same-level recurrence along the
+    #: lanes, ``x_j = c_j + lane_decay·x_{j−1}``, which its ``update`` runs
+    #: as `_lane_recurrence`; it sets how many scan levels the kernel emits
+    lane_decay: float = 1.0
     #: the ``pallas_call``'s name, which the device trace shows; None keeps
     #: JAX's default, the name of the jitted caller
     trace_name: Optional[str] = None
@@ -113,15 +118,19 @@ class StencilProgram:
         (``shape[0] / block`` blocks and ``radius·steps / block`` flush
         blocks).  A Jacobi grid step is ``steps`` time steps of one vector
         step each: the block's items at once.  An in-place one is ``steps +
-        block − 1`` wavefronts, each one stencil sum and then the
-        `lane_scan_levels` of the lane recurrence over the width ``W =
-        shape[-1]``: ``(steps + block − 1) · (1 + ⌈log2 W⌉)``.  Of a
-        wavefront's items, ``steps / (steps + block − 1)`` do work on
-        average; the rest wait for the diagonal to reach or leave them."""
+        block − 1`` wavefronts, each one stencil sum and then the levels
+        the lane recurrence emits over the width ``W = shape[-1]``,
+        `lane_scan_levels` of ``W`` and ``lane_decay``: ``(steps + block −
+        1) · (1 + L)``, with ``L`` at most ``⌈log2 W⌉`` and fewer where
+        ``lane_decay``'s powers reach 0.0 in float32 (seidel-2d at W = 4000:
+        6 of 12).  Of a wavefront's items, ``steps / (steps + block − 1)``
+        do work on average; the rest wait for the diagonal to reach or
+        leave them."""
         grid = (shape[0] + self.radius * steps) // block
         if not self.in_place:
             return grid * steps
-        return grid * (steps + block - 1) * (1 + lane_scan_levels(shape[-1]))
+        levels = lane_scan_levels(shape[-1], self.lane_decay)
+        return grid * (steps + block - 1) * (1 + levels)
 
 
 def _shift_inner(a: jnp.ndarray, axis: int, off: int) -> jnp.ndarray:
@@ -160,22 +169,41 @@ def _heat3d_update(up, center, down):
             + 0.125 * (kl - 2.0 * center + kr))
 
 
-def lane_scan_levels(width: int) -> int:
-    """Levels of a log-step scan across ``width`` lanes: shifts of 1, 2, 4,
-    … lanes reach ``2**levels − 1 ≥ width − 1`` lanes back."""
-    return (width - 1).bit_length()
+def lane_scan_levels(width: int, decay: float = 1.0) -> int:
+    """Levels of a log-step scan across ``width`` lanes whose factor
+    ``decay**(2**d)`` is nonzero in float32: shifts of 1, 2, 4, … lanes
+    reach ``2**levels − 1 ≥ width − 1`` lanes back, unless the factor of a
+    level rounds to exactly 0.0 first, as every higher power then does.  A
+    subnormal factor is not zero, and its level stays."""
+    levels = 0
+    while (levels < (width - 1).bit_length()
+           and np.float32(decay ** (2 ** levels)) != 0):
+        levels += 1
+    return levels
 
 
 def _lane_recurrence(c: jnp.ndarray, decay: float) -> jnp.ndarray:
     """``x_j = c_j + decay·x_{j−1}`` along the last axis, ``x_{−1} = 0``,
-    as a log-step (Hillis–Steele) scan over the whole width: after level
-    ``d`` each ``x_j`` holds ``Σ_{m < 2^(d+1)} decay^m c_{j−m}``.  Every
-    level runs, whatever ``decay^(2^d)`` rounds to in float32, so the code
-    leaves no term out."""
+    as a log-step (Hillis–Steele) scan: after level ``d`` each ``x_j``
+    holds ``Σ_{m < 2^(d+1)} decay^m c_{j−m}``.  Over the whole width that
+    takes ``⌈log2 W⌉`` levels, and no window of lanes may be left out: with
+    decay 1 the first term weighs on the last lane as on the second.  The
+    loop stops only at the first level whose float32 factor
+    ``decay^(2^d)`` is exactly 0.0 (`lane_scan_levels`): that level and
+    every later one would add ``0.0·shift(x)``, which for finite ``x``
+    changes no bit of ``x`` but a −0 turned to +0.  So the result is the
+    full scan's, less multiplications by an exact zero (seidel-2d's 1/9
+    reaches 0.0 at ``2^6``: 6 levels of 12 at W = 4000)."""
     x = c
-    for d in range(lane_scan_levels(c.shape[-1])):
+    for d in range(lane_scan_levels(c.shape[-1], decay)):
         x = x + decay ** (2 ** d) * _shift_inner(x, -1, 2 ** d)
     return x
+
+
+#: seidel-2d's weight of ``A[i][j−1]`` in its 9-point average: the decay of
+#: its lane recurrence, read by `_seidel2d_update` and by the program's
+#: ``lane_decay`` (and so by ``dependent_steps``)
+_SEIDEL_DECAY = 1.0 / 9.0
 
 
 def _seidel2d_update(up, center, down):
@@ -185,7 +213,7 @@ def _seidel2d_update(up, center, down):
     c = (_shift_inner(up, -1, +1) + up + _shift_inner(up, -1, -1)
          + center + _shift_inner(center, -1, -1)
          + _shift_inner(down, -1, +1) + down + _shift_inner(down, -1, -1))
-    return _lane_recurrence(c / 9.0, 1.0 / 9.0)
+    return _lane_recurrence(c / 9.0, _SEIDEL_DECAY)
 
 
 def _lazy_ref(module: str, fn: str):
@@ -210,7 +238,8 @@ STENCIL_PROGRAMS: Dict[str, StencilProgram] = {
     "seidel-2d": StencilProgram(
         "seidel-2d", radius=1, inner_rank=1, update=_seidel2d_update,
         ref=_lazy_ref("repro.kernels.stencil_bands.ref", "seidel_2d"),
-        in_place=True, trace_name="seidel_2d_ring"),
+        in_place=True, lane_decay=_SEIDEL_DECAY,
+        trace_name="seidel_2d_ring"),
 }
 
 

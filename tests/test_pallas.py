@@ -299,14 +299,80 @@ def test_lane_recurrence_spans_the_whole_width():
         assert np.array_equal(np.asarray(got), np.cumsum(c, axis=1)), width
 
 
+def _full_depth_scan(c, decay):
+    """The Hillis–Steele scan with all ``⌈log2 W⌉`` levels, zero factors
+    included: level ``d`` adds ``decay**(2**d)`` times ``x`` shifted
+    ``2**d`` lanes right."""
+    x = c
+    for d in range((c.shape[-1] - 1).bit_length()):
+        shifted = jnp.pad(x, ((0, 0), (2 ** d, 0)))[:, :c.shape[-1]]
+        x = x + decay ** (2 ** d) * shifted
+    return x
+
+
+@pytest.mark.parametrize("scale", [1e-3, 1.0, 1e3])
+@pytest.mark.parametrize("decay", [1 / 9, 0.5, 0.9, 1.0],
+                         ids=["1/9", "0.5", "0.9", "1.0"])
+@pytest.mark.parametrize("width", [20, 64, 65, 130, 256, 4000])
+def test_lane_recurrence_equals_the_full_depth_scan(width, decay, scale):
+    """Leaving out the levels whose float32 factor is 0.0 changes no bit:
+    the truncated scan equals the full one under ``==``.  Both run op by
+    op, each product and sum rounded on its own: jitted, XLA's CPU fusions
+    of the two graphs may round a product-and-sum differently, which says
+    nothing of the levels left out."""
+    from repro.runtime.pallas_codegen import _lane_recurrence
+    rng = np.random.default_rng(width)
+    c = jnp.asarray(scale * rng.standard_normal((8, width)), jnp.float32)
+    got = _lane_recurrence(c, decay)
+    assert np.array_equal(np.asarray(got), np.asarray(_full_depth_scan(c, decay)))
+
+
+@pytest.mark.parametrize("decay,levels", [(1 / 9, 6), (0.5, 8), (0.9, 10),
+                                          (1.0, 12)],
+                         ids=["1/9", "0.5", "0.9", "1.0"])
+def test_lane_scan_levels_stop_at_the_first_zero_factor(decay, levels):
+    """At W = 4000 the scan has 12 levels; ``decay**(2**d)`` is 0.0 in
+    float32 from d = 6 for 1/9, d = 8 for 0.5 (2**-128 is subnormal, and
+    kept) and d = 10 for 0.9; for 1.0 it never is."""
+    from repro.runtime.pallas_codegen import lane_scan_levels
+    assert lane_scan_levels(4000, decay) == levels
+    assert np.float32(decay ** (2 ** (levels - 1))) != 0
+    if levels < 12:
+        assert np.float32(decay ** (2 ** levels)) == 0
+
+
+def _count_mul(jaxpr) -> int:
+    """``mul`` equations in ``jaxpr`` and the jaxprs nested in it."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        count += eqn.primitive.name == "mul"
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                count += _count_mul(inner)
+    return count
+
+
+def test_seidel_update_emits_the_levels_dependent_steps_counts():
+    """The seidel-2d update multiplies once per scan level and nowhere else
+    (its 9-point sum divides), so its ``mul`` count at W = 4000 is the
+    scan depth that ``dependent_steps`` charges each wavefront."""
+    seidel = STENCIL_PROGRAMS["seidel-2d"]
+    row = jnp.zeros((8, 4000), jnp.float32)
+    muls = _count_mul(jax.make_jaxpr(seidel.update)(row, row, row).jaxpr)
+    per_wavefront = seidel.dependent_steps((4000, 4000), 200, 8) // (525 * 207)
+    assert muls == per_wavefront - 1 == 6
+
+
 def test_dependent_steps_counts_the_chain():
     """Grid steps × the serial depth of one: ``steps`` for a Jacobi
-    program, ``(steps + block − 1)`` wavefronts of ``1 + ⌈log2 W⌉`` for an
-    in-place one."""
+    program, ``(steps + block − 1)`` wavefronts of one stencil sum and the
+    emitted lane scan levels for an in-place one: at W = 4000, 6 of
+    ``⌈log2 W⌉ = 12``, as seidel-2d's ``(1/9)**64`` is 0.0 in float32."""
     jacobi, seidel = STENCIL_PROGRAMS["jacobi-2d"], STENCIL_PROGRAMS["seidel-2d"]
     assert jacobi.dependent_steps((2800, 2800), 200, 8) == (350 + 25) * 200
     assert seidel.dependent_steps((4000, 4000), 200, 8) == \
-        (500 + 25) * 207 * 13
+        (500 + 25) * 207 * 7
     assert seidel.dependent_steps((16, 20), 8, 1) == (16 + 8) * 8 * 1 * 6
 
 
