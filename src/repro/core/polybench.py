@@ -1,4 +1,5 @@
-"""PolyBench/C 3.2 kernels as polyhedral specs (paper §4 experimental setup).
+"""PolyBench/C kernels as polyhedral specs (paper §4 experimental setup:
+PolyBench/C 3.2; adi follows 4.2.1).
 
 Every kernel is authored with the declarative `repro.lang` frontend
 (`docs/frontend.md`): loop nests built with ``Nest.loop``/``Nest.stmt``,
@@ -436,6 +437,68 @@ def heat_3d(scale: int = 1) -> Nest:
     # interleave 2t / 2t+1 to stay valid: φ = ((2t+s)/b, (2t+s+i)/b).
     k.tile("sb", Tiling(((2, 0, 0, 0), (2, 1, 0, 0)), (2 * b, 2 * b), (0, 0)))
     k.tile("sa", Tiling(((2, 0, 0, 0), (2, 1, 0, 0)), (2 * b, 2 * b), (1, 1)))
+    return k
+
+
+@register("adi")
+def adi(scale: int = 1) -> Nest:
+    """PolyBench/C 4.2.1 ``stencils/adi``: each time step a column sweep
+    (for every column ``i`` a tridiagonal solve down the rows of ``u``,
+    into ``v``) and a row sweep (along each row of ``v``, into ``u``).  A
+    solve is a forward elimination (``p``, ``q``) and a back substitution;
+    ``repro.lang`` has ascending loops only, so PolyBench's ``for (j =
+    N-2; j >= 1; j--)`` is ``jj`` from 0 with ``j = N-2-jj``.  The
+    coefficients are scalars and carry no dataflow; the spec keeps every
+    array access of the C source.
+
+    Only ``u`` is carried from one step to the next; ``v``, ``p`` and ``q``
+    are rewritten inside every step.  The back substitution reads ``p`` and
+    ``q`` in the reverse of the order they were written, and the row sweep
+    reads ``v`` by rows where the column sweep wrote it by columns (and the
+    next column sweep reads ``u`` by columns): no partition of the tiled
+    iteration space makes those channels FIFO (``docs/pallas.md``)."""
+    k = Nest("adi")
+    N, T, b = k.param("N", 10 * scale), k.param("T", 4 * scale), 2
+    u, v = k.array("u", N, N), k.array("v", N, N)
+    p, q = k.array("p", N, N), k.array("q", N, N)
+    k.inputs(u)
+    k.outputs(u)
+    with k.loop("t", 0, T):
+        with k.loop("i", 1, N - 1) as i:                  # column sweep
+            k.stmt("cv0", writes=[v[0, i]])
+            k.stmt("cp0", writes=[p[i, 0]])
+            k.stmt("cq0", writes=[q[i, 0]], reads=[v[0, i]])
+            with k.loop("j", 1, N - 1) as j:
+                k.stmt("cp", writes=[p[i, j]], reads=[p[i, j - 1]])
+                k.stmt("cq", writes=[q[i, j]],
+                       reads=[u[j, i - 1], u[j, i], u[j, i + 1],
+                              q[i, j - 1], p[i, j - 1]])
+            k.stmt("cvn", writes=[v[N - 1, i]])
+            with k.loop("jj", 0, N - 2) as jj:
+                k.stmt("cv", writes=[v[N - 2 - jj, i]],
+                       reads=[p[i, N - 2 - jj], v[N - 1 - jj, i],
+                              q[i, N - 2 - jj]])
+        with k.loop("i", 1, N - 1) as i:                  # row sweep
+            k.stmt("ru0", writes=[u[i, 0]])
+            k.stmt("rp0", writes=[p[i, 0]])
+            k.stmt("rq0", writes=[q[i, 0]], reads=[u[i, 0]])
+            with k.loop("j", 1, N - 1) as j:
+                k.stmt("rp", writes=[p[i, j]], reads=[p[i, j - 1]])
+                k.stmt("rq", writes=[q[i, j]],
+                       reads=[v[i - 1, j], v[i, j], v[i + 1, j],
+                              q[i, j - 1], p[i, j - 1]])
+            k.stmt("run", writes=[u[i, N - 1]])
+            with k.loop("jj", 0, N - 2) as jj:
+                k.stmt("ru", writes=[u[i, N - 2 - jj]],
+                       reads=[p[i, N - 2 - jj], u[i, N - 1 - jj],
+                              q[i, N - 2 - jj]])
+    # every step's column sweep reads all of u and its row sweep all of v,
+    # so no hyperplane but t keeps the dependences between tiles forward:
+    # tiles of b time steps, the rest of each step inside one tile
+    for name in ("cv0", "cp0", "cq0", "cvn", "ru0", "rp0", "rq0", "run"):
+        k.tile(name, Tiling(((1, 0),), (b,)))
+    for name in ("cp", "cq", "cv", "rp", "rq", "ru"):
+        k.tile(name, Tiling(((1, 0, 0),), (b,)))
     return k
 
 

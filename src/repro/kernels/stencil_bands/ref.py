@@ -1,14 +1,18 @@
-"""Pure-jnp oracles for the band stencils the pallas backend generates.
+"""Pure-jnp oracles for the stencils the pallas backend generates.
 
-Every cell updates every step, with zero (Dirichlet) values outside the
-array.  The update formulas mirror `runtime.pallas_codegen.STENCIL_PROGRAMS`
-exactly — the parity tests compare the generated fused VMEM-ring kernels
-against these, so the two must stay in lockstep.
+In the band stencils every cell updates every step, with zero (Dirichlet)
+values outside the array; adi keeps PolyBench's own boundaries (`adi`).
+The update formulas mirror `runtime.pallas_codegen.STENCIL_PROGRAMS`
+exactly — the parity tests compare the generated kernels against these,
+so the two must stay in lockstep.
 """
 from __future__ import annotations
 
+from typing import Tuple
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _iterate(step, a0: jnp.ndarray, steps: int) -> jnp.ndarray:
@@ -77,3 +81,70 @@ def seidel_2d(a0: jnp.ndarray, steps: int) -> jnp.ndarray:
         return rows
     with jax.default_matmul_precision("highest"):
         return _iterate(step, a0, steps)
+
+
+def adi_coefficients(n: int) -> Tuple[float, ...]:
+    """PolyBench 4.2.1 adi's ``(a, b, c, d, e, f)`` for an ``n × n`` grid,
+    computed in float32 as its ``kernel_adi`` does: ``DX = DY = 1/N``,
+    ``DT = 1/TSTEPS``, ``B1 = 2``, ``B2 = 1``, ``mul1 = B1·DT/DX²``,
+    ``mul2 = B2·DT/DY²``, ``a = c = −mul1/2``, ``b = 1 + mul1``, ``d = f =
+    −mul2/2``, ``e = 1 + mul2``.  A step sees only the array, so TSTEPS is
+    taken as ``N // 2``, PolyBench's own at MEDIUM, LARGE and EXTRALARGE
+    (N = 2000: a = −4000, b = 8001, d = −2000, e = 4001)."""
+    f32 = np.float32
+    dx = dy = f32(1.0) / f32(n)
+    dt = f32(1.0) / f32(n // 2)
+    mul1 = f32(2.0) * dt / (dx * dx)
+    mul2 = f32(1.0) * dt / (dy * dy)
+    a = -mul1 / f32(2.0)
+    d = -mul2 / f32(2.0)
+    return tuple(float(x) for x in (a, f32(1.0) + mul1, a,
+                                    d, f32(1.0) + mul2, d))
+
+
+def _line_solve(rhs: jnp.ndarray, lo: float, diag: float,
+                up: float) -> jnp.ndarray:
+    """The tridiagonal solve ``lo·x[j−1] + diag·x[j] + up·x[j+1] =
+    rhs[j−1]`` for ``0 < j < m − 1`` along axis 0, with ``x[0] = x[m−1] =
+    1``, every column at once, as PolyBench writes it: a forward
+    elimination ``p[j] = −up / (lo·p[j−1] + diag)``, ``q[j] = (rhs − lo·
+    q[j−1]) / (lo·p[j−1] + diag)`` from ``p[0] = 0``, ``q[0] = 1``, then a
+    back substitution ``x[j] = p[j]·x[j+1] + q[j]``; both sequential."""
+    ones = jnp.ones_like(rhs[0])
+
+    def forward(carry, r):
+        p, q = carry
+        den = lo * p + diag
+        p, q = -up / den, (r - lo * q) / den
+        return (p, q), (p, q)
+
+    def back(x, pq):
+        x = pq[0] * x + pq[1]
+        return x, x
+
+    _, pq = jax.lax.scan(forward, (jnp.zeros_like(ones), ones), rhs)
+    _, x = jax.lax.scan(back, ones, pq, reverse=True)
+    return jnp.concatenate([ones[None], x, ones[None]])
+
+
+def adi(u0: jnp.ndarray, steps: int) -> jnp.ndarray:
+    """T steps of PolyBench 4.2.1 adi on the ``n × n`` array ``u``: a
+    column sweep (for each column ``i`` in ``1 … n−2``, the solve down the
+    rows with coefficients ``a, b, c`` and right-hand side ``−d·u[j][i−1]
+    + (1+2d)·u[j][i] − f·u[j][i+1]``, into ``v``) and a row sweep (for each
+    row, the solve along it with ``d, e, f`` and ``−a·v[i−1][j] +
+    (1+2a)·v[i][j] − c·v[i+1][j]``, into ``u``).  Rows 0 and ``n−1`` of
+    ``u`` are never written; columns 0 and ``n−1`` of the rows between
+    become 1 (`adi_coefficients` gives the constants)."""
+    a, b, c, d, e, f = adi_coefficients(u0.shape[0])
+    kd = float(np.float32(1.0) + np.float32(2.0) * np.float32(d))
+    ka = float(np.float32(1.0) + np.float32(2.0) * np.float32(a))
+
+    def step(u):
+        v = _line_solve(-d * u[1:-1, :-2] + kd * u[1:-1, 1:-1]
+                        - f * u[1:-1, 2:], a, b, c)
+        rows = _line_solve((-a * v[:-2] + ka * v[1:-1] - c * v[2:]).T,
+                           d, e, f).T
+        return u.at[1:-1].set(rows)
+    with jax.default_matmul_precision("highest"):
+        return _iterate(step, u0, steps)

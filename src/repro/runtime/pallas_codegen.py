@@ -44,6 +44,10 @@ is ``r`` items per time step so tile writes stay block-aligned):
   `VMEM_LIMIT_BYTES`; `CompiledStencil` refuses other geometries by name
   before lowering.
 
+adi is not a band stencil: its line solves are a program kind of their
+own (`LineSolveProgram`), two whole-array kernels a step, since its plan
+keeps reversed and transposed channels (``docs/pallas.md``).
+
 `compile_analysis` is the `Analysis.compile(backend="pallas")` entry point:
 it reads the `.plan()` records, picks the VMEM-ring mode iff every planned
 lowering is a stream/register (`is_cheap`), and binds the kernel's
@@ -64,6 +68,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..kernels.stencil_bands.ref import adi_coefficients
 from .lowering import is_cheap
 
 
@@ -182,7 +187,34 @@ def lane_scan_levels(width: int, decay: float = 1.0) -> int:
     return levels
 
 
-def _lane_recurrence(c: jnp.ndarray, decay: float) -> jnp.ndarray:
+def lane_scan_factors(decay: np.ndarray, reverse: bool = False) -> np.ndarray:
+    """The per-lane factors of each level of the log-step scan of ``x_j =
+    c_j + decay_j·x_{j−1}`` (``x_{j+1}`` with ``reverse``), in float32:
+    level 0's are ``decay``, level ``d + 1``'s the product of level ``d``'s
+    at ``j`` and at ``j ∓ 2^d`` (0 past the edge), so that level ``d``
+    weighs ``x`` shifted ``2^d`` lanes by the product of the ``2^d``
+    decays it crosses.  Shape ``(levels, W)``: ``⌈log2 W⌉`` levels, less
+    those from the first whose factors are all exactly 0.0, as every later
+    level's then are (`lane_scan_levels`, for one decay on every lane)."""
+    f = np.asarray(decay, np.float32)
+    width = f.shape[-1]
+    levels = []
+    for d in range((width - 1).bit_length()):
+        if not f.any():
+            break
+        levels.append(f)
+        shift = 2 ** d
+        moved = np.zeros_like(f)
+        if reverse:
+            moved[..., :width - shift] = f[..., shift:]
+        else:
+            moved[..., shift:] = f[..., :width - shift]
+        f = f * moved
+    return np.stack(levels) if levels else np.zeros((0, width), np.float32)
+
+
+def _lane_recurrence(c: jnp.ndarray, decay, reverse: bool = False
+                     ) -> jnp.ndarray:
     """``x_j = c_j + decay·x_{j−1}`` along the last axis, ``x_{−1} = 0``,
     as a log-step (Hillis–Steele) scan: after level ``d`` each ``x_j``
     holds ``Σ_{m < 2^(d+1)} decay^m c_{j−m}``.  Over the whole width that
@@ -193,10 +225,18 @@ def _lane_recurrence(c: jnp.ndarray, decay: float) -> jnp.ndarray:
     every later one would add ``0.0·shift(x)``, which for finite ``x``
     changes no bit of ``x`` but a −0 turned to +0.  So the result is the
     full scan's, less multiplications by an exact zero (seidel-2d's 1/9
-    reaches 0.0 at ``2^6``: 6 levels of 12 at W = 4000)."""
+    reaches 0.0 at ``2^6``: 6 levels of 12 at W = 4000).
+
+    ``decay`` is one float for every lane, or the per-lane factors of each
+    level (`lane_scan_factors`, each broadcastable to ``c``) for ``x_j =
+    c_j + decay_j·x_{j−1}``; ``reverse`` runs the recurrence from the last
+    lane down, ``x_j = c_j + decay_j·x_{j+1}`` with ``x_W = 0``."""
+    if isinstance(decay, float):
+        decay = [decay ** (2 ** d)
+                 for d in range(lane_scan_levels(c.shape[-1], decay))]
     x = c
-    for d in range(lane_scan_levels(c.shape[-1], decay)):
-        x = x + decay ** (2 ** d) * _shift_inner(x, -1, 2 ** d)
+    for d, factor in enumerate(decay):
+        x = x + factor * _shift_inner(x, -1, -2 ** d if reverse else 2 ** d)
     return x
 
 
@@ -216,6 +256,223 @@ def _seidel2d_update(up, center, down):
     return _lane_recurrence(c / 9.0, _SEIDEL_DECAY)
 
 
+# ----------------------------------------------------------- line solves --
+
+@functools.lru_cache(maxsize=None)
+def _adi_tables(n: int) -> Dict[str, np.ndarray]:
+    """The data-independent halves of adi's line solves over ``n`` cells,
+    in float32 (PolyBench's ``p`` recurrence, hoisted: the same for every
+    line and every step, so computing it once per call is exact):
+
+    * ``den_col``, ``p_col``: the column sweep's ``a·p[j−1] + b`` and
+      ``p[j] = −c/(a·p[j−1] + b)``, one scalar a row (``p[0] = p[n−1] =
+      0`` at the boundary rows, where ``den_col`` is 1 and unused);
+    * ``lanes``: the row sweep's ``1/(d·p[j−1] + e)`` and the one-hot first
+      and last lanes, where its solve starts and ends at 1;
+    * ``fwd``, ``bwd``: the row sweep's scan factors (`lane_scan_factors`)
+      of ``q[j] = γ[j] + α[j]·q[j−1]``, ``α[j] = −d/(d·p[j−1] + e)``, and
+      of ``u[j] = q[j] + p[j]·u[j+1]``, both 0 at lanes 0 and ``n−1``."""
+    a, b, c, d, e, f = (np.float32(k) for k in adi_coefficients(n))
+
+    def elimination(lo, diag, up):
+        p, r = np.zeros(n, np.float32), np.zeros(n, np.float32)
+        den = np.ones(n, np.float32)
+        for j in range(1, n - 1):
+            den[j] = lo * p[j - 1] + diag
+            p[j] = -up / den[j]
+            r[j] = np.float32(1.0) / den[j]
+        return p, r, den
+
+    p_col, _, den_col = elimination(a, b, c)
+    p_row, r_row, den_row = elimination(d, e, f)
+    alpha = np.zeros(n, np.float32)
+    alpha[1:n - 1] = -d / den_row[1:n - 1]
+    first, last = np.zeros(n, np.float32), np.zeros(n, np.float32)
+    first[0] = last[n - 1] = 1.0
+    return {"den_col": den_col, "p_col": p_col,
+            "lanes": np.stack([r_row, first, last]),
+            "fwd": lane_scan_factors(alpha),
+            "bwd": lane_scan_factors(p_row, reverse=True)}
+
+
+def _adi_column_kernel(den_ref, p_ref, u_ref, v_ref, q_ref, *, block: int,
+                       coef: Tuple[float, ...]):
+    """adi's column sweep over the whole array: for every column at once
+    (columns in the lanes), the forward elimination down the rows, one row
+    a step, into the VMEM buffer ``q``, then the back substitution up them,
+    into ``v``.  ``q`` is read in the reverse of the order it was written:
+    an addressable buffer, not a ring.  Each block of ``block`` rows takes
+    its right-hand side ``−d·u[j][i−1] + (1+2d)·u[j][i] − f·u[j][i+1]``
+    at once."""
+    a, d, f, kd = coef
+    n = u_ref.shape[0]
+    nb = n // block
+
+    def forward(k, q):
+        base = pl.multiple_of(k * block, block)
+        ub = u_ref[pl.ds(base, block), :]
+        rhs = (-d * _shift_inner(ub, -1, 1) + kd * ub
+               - f * _shift_inner(ub, -1, -1))
+        rows = []
+        for s in range(block):
+            j = base + s
+            q = jnp.where((j >= 1) & (j <= n - 2),
+                          (rhs[s:s + 1] - a * q) / den_ref[j], 1.0)
+            rows.append(q)
+        q_ref[pl.ds(base, block), :] = jnp.concatenate(rows)
+        return q
+
+    def backward(k, v):
+        base = pl.multiple_of((nb - 1 - k) * block, block)
+        qb = q_ref[pl.ds(base, block), :]
+        rows = [None] * block
+        for s in reversed(range(block)):
+            v = p_ref[base + s] * v + qb[s:s + 1]
+            rows[s] = v
+        v_ref[pl.ds(base, block), :] = jnp.concatenate(rows)
+        return v
+
+    zero = jnp.zeros((1, n), jnp.float32)
+    jax.lax.fori_loop(0, nb, forward, zero)
+    jax.lax.fori_loop(0, nb, backward, zero)
+
+
+def _adi_row_kernel(lanes_ref, fwd_ref, bwd_ref, v_ref, top_ref, bottom_ref,
+                    u_ref, *, block: int, coef: Tuple[float, ...]):
+    """adi's row sweep over the whole array: each block of ``block`` rows
+    takes its right-hand side ``−a·v[i−1][j] + (1+2a)·v[i][j] −
+    c·v[i+1][j]``, then runs both recurrences along the lanes as log-step
+    scans of affine maps (`_lane_recurrence` with per-lane factors), the
+    forward elimination and, backward, the substitution.  Rows 0 and
+    ``n−1`` keep the old ``u`` (``top_ref``, ``bottom_ref``: its first and
+    last blocks)."""
+    a, c, ka = coef
+    n = v_ref.shape[0]
+    nb = n // block
+    ids = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+
+    def body(k, carry):
+        base = pl.multiple_of(k * block, block)
+        cur = v_ref[pl.ds(base, block), :]
+        prev = v_ref[pl.ds(pl.multiple_of(jnp.maximum(base - block, 0),
+                                          block), block), :]
+        nxt = v_ref[pl.ds(pl.multiple_of(jnp.minimum(base + block, n - block),
+                                         block), block), :]
+        up = jnp.concatenate([prev[block - 1:], cur[:block - 1]])
+        down = jnp.concatenate([cur[1:], nxt[:1]])
+        rhs = -a * up + ka * cur - c * down
+        q = _lane_recurrence(rhs * lanes_ref[0] + lanes_ref[1],
+                             [fwd_ref[d] for d in range(fwd_ref.shape[0])])
+        new = _lane_recurrence(q + lanes_ref[2],
+                               [bwd_ref[d] for d in range(bwd_ref.shape[0])],
+                               reverse=True)
+        row = base + ids
+        new = jnp.where(row == 0, top_ref[...], new)
+        u_ref[pl.ds(base, block), :] = jnp.where(row == n - 1,
+                                                 bottom_ref[...], new)
+        return carry
+
+    jax.lax.fori_loop(0, nb, body, 0)
+
+
+@dataclass(frozen=True)
+class LineSolveProgram:
+    """adi, a program of tridiagonal line solves rather than a band
+    stencil: each time step a column sweep (a solve down every column of
+    ``u``, into ``v``) and a row sweep (along every row of ``v``, into
+    ``u``), each a first-order recurrence forward and another backward.
+    Its plan keeps the reversed (``p``, ``q``) and transposed (``u``,
+    ``v``) channels non-FIFO, so it has no ring kernel: each sweep is one
+    whole-array ``pallas_call`` a step, named by ``trace_name``, with
+    ``q`` in an addressable VMEM buffer (`_adi_column_kernel`,
+    `_adi_row_kernel`)."""
+
+    name: str
+    ref: Callable[[jnp.ndarray, int], jnp.ndarray]
+    #: the column and the row sweep's ``pallas_call`` names
+    trace_name: Tuple[str, str] = ("adi_column_sweep", "adi_row_sweep")
+
+    def dependent_steps(self, shape: Tuple[int, ...], steps: int,
+                        block: int) -> int:
+        """The longest chain of dependent vector steps in one call of
+        ``steps`` time steps over an ``n × n`` array: per step, the column
+        sweep's ``n − 2`` rows of elimination and ``n − 2`` of
+        substitution, one a step, then one block's row sweep (its blocks
+        are independent): the right-hand side and the levels of its two
+        lane scans (`lane_scan_factors`; at n = 2000 all 11 of each)."""
+        n = shape[0]
+        tables = _adi_tables(n)
+        row = 1 + len(tables["fwd"]) + len(tables["bwd"])
+        return steps * (2 * (n - 2) + row)
+
+    def check(self, shape: Tuple[int, ...], block: int,
+              interpret: bool) -> None:
+        """Refuses, by name and before lowering, an array that is not
+        square, a block that does not divide it, and (compiled for a TPU)
+        a block of other than whole sublane rows or sweeps that do not fit
+        `VMEM_LIMIT_BYTES`."""
+        n = shape[0]
+        if len(shape) != 2 or shape[1] != n:
+            raise ValueError(f"{self.name}: expected a square array, got "
+                             f"shape {shape}")
+        if n % block:
+            raise ValueError(f"{self.name}: block {block} must divide the "
+                             f"{n} rows")
+        if interpret:
+            return
+        if block % _SUBLANES:
+            raise ValueError(f"{self.name} takes rows in blocks on the "
+                             f"sublane axis: block {block} must be a "
+                             f"multiple of {_SUBLANES} when compiled for a "
+                             f"TPU")
+        # the row sweep holds two whole arrays and a few blocks: less
+        _check_vmem(f"{self.name} column sweep over {shape} (u, v and q "
+                    f"whole in VMEM)", 3 * _vmem_bytes(shape))
+
+    def __call__(self, x: jnp.ndarray, steps: int, block: int,
+                 interpret: bool) -> jnp.ndarray:
+        self.check(x.shape, block, interpret)
+        n = x.shape[0]
+        tables = _adi_tables(n)
+        a, b, c, d, e, f = adi_coefficients(n)
+        kd = float(np.float32(1.0) + np.float32(2.0) * np.float32(d))
+        ka = float(np.float32(1.0) + np.float32(2.0) * np.float32(a))
+        vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+        smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+        params = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+        out = jax.ShapeDtypeStruct((n, n), jnp.float32)
+        column = pl.pallas_call(
+            functools.partial(_adi_column_kernel, block=block,
+                              coef=(a, d, f, kd)),
+            out_shape=out, in_specs=[smem, smem, vmem], out_specs=vmem,
+            scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
+            compiler_params=params, interpret=interpret,
+            name=self.trace_name[0])
+        nb = n // block
+        row = pl.pallas_call(
+            functools.partial(_adi_row_kernel, block=block, coef=(a, c, ka)),
+            out_shape=out,
+            grid=(1,),                # a grid, for the two blocks of u
+            in_specs=[vmem, vmem, vmem, vmem,
+                      pl.BlockSpec((block, n), lambda _: (0, 0)),
+                      pl.BlockSpec((block, n), lambda _: (nb - 1, 0))],
+            out_specs=vmem, compiler_params=params, interpret=interpret,
+            name=self.trace_name[1])
+
+        def rows(t):                  # a per-lane table on every row
+            return jnp.asarray(np.broadcast_to(
+                t[..., None, :], t.shape[:-1] + (block, n)))
+
+        den_col, p_col = (jnp.asarray(tables[k]) for k in ("den_col",
+                                                            "p_col"))
+        lanes, fwd, bwd = (rows(tables[k]) for k in ("lanes", "fwd", "bwd"))
+
+        def step(_, u):
+            return row(lanes, fwd, bwd, column(den_col, p_col, u), u, u)
+
+        return jax.lax.fori_loop(0, steps, step, x)
+
+
 def _lazy_ref(module: str, fn: str):
     def call(a0, steps):
         import importlib
@@ -223,9 +480,10 @@ def _lazy_ref(module: str, fn: str):
     return call
 
 
-#: kernel-registry name → band-stencil semantics.  The analysis plans the
-#: channels; this table supplies the arithmetic the PPN does not carry.
-STENCIL_PROGRAMS: Dict[str, StencilProgram] = {
+#: kernel-registry name → its semantics: a band stencil, or adi's line
+#: solves.  The analysis plans the channels; this table supplies the
+#: arithmetic the PPN does not carry.
+STENCIL_PROGRAMS: Dict[str, StencilProgram | LineSolveProgram] = {
     "jacobi-1d": StencilProgram(
         "jacobi-1d", radius=1, inner_rank=0, update=_jacobi1d_update,
         ref=_lazy_ref("repro.kernels.stencil_bands.ref", "jacobi_1d")),
@@ -240,6 +498,8 @@ STENCIL_PROGRAMS: Dict[str, StencilProgram] = {
         ref=_lazy_ref("repro.kernels.stencil_bands.ref", "seidel_2d"),
         in_place=True, lane_decay=_SEIDEL_DECAY,
         trace_name="seidel_2d_ring"),
+    "adi": LineSolveProgram(
+        "adi", ref=_lazy_ref("repro.kernels.stencil_bands.ref", "adi")),
 }
 
 
@@ -433,6 +693,8 @@ class CompiledStencil:
         interpret = default_interpret() if interpret is None else interpret
         p = self.program
         x = x.astype(jnp.float32)
+        if isinstance(p, LineSolveProgram):
+            return p(x, steps, block, interpret)
         if len(x.shape) != p.inner_rank + 1:
             raise ValueError(f"{p.name}: expected rank {p.inner_rank + 1} "
                              f"input, got shape {x.shape}")
@@ -518,7 +780,6 @@ class CompiledStencil:
 
     def describe(self) -> str:
         return (f"CompiledStencil[{self.program.name}] mode={self.mode} "
-                f"radius={self.program.radius} "
                 f"plans={len(self.plans)} ({self.diagnostics})")
 
 
@@ -564,7 +825,11 @@ def compile_analysis(analysis, mode: Optional[str] = None) -> CompiledStencil:
             f".fifoize() first, or compile mode='addressable')")
     if mode not in ("fifo-ring", "addressable"):
         raise ValueError(f"unknown mode {mode!r}")
-    if mode == "addressable" and program.in_place:
+    if mode == "fifo-ring" and isinstance(program, LineSolveProgram):
+        raise ValueError(f"{name}: line solves have no ring kernel; only "
+                         f"the addressable mode runs them")
+    if (mode == "addressable" and isinstance(program, StencilProgram)
+            and program.in_place):
         raise ValueError(
             f"{name}: the addressable mode has no in-place step — its "
             f"whole-array kernel would sweep {name} as Jacobi does; only "

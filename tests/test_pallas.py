@@ -36,6 +36,7 @@ from repro.runtime import (LOWERINGS, FIFO_STREAM,  # noqa: E402
 from repro.runtime.pallas_backend import RingOverflow  # noqa: E402
 from repro.runtime.pallas_codegen import STENCIL_PROGRAMS  # noqa: E402
 
+from test_polybench import ADI_OUT_OF_ORDER  # noqa: E402
 from test_runtime import CASES, two_proc_ppn  # noqa: E402
 
 ATOL = dict(rtol=1e-5, atol=1e-5)
@@ -485,6 +486,168 @@ def test_stencil_programs_mirror_registered_kernels():
     from repro.core.registry import kernel_names
 
     assert set(STENCIL_PROGRAMS) <= set(kernel_names())
+
+
+# ------------------------------------------------------ line solves (adi) --
+
+
+def _adi_loop(u0, steps):
+    """PolyBench 4.2.1's ``kernel_adi`` loop nest in float64, statement by
+    statement, with the constants of `adi_coefficients`."""
+    from repro.kernels.stencil_bands.ref import adi_coefficients
+    n = u0.shape[0]
+    a, b, c, d, e, f = adi_coefficients(n)
+    u = u0.astype(np.float64)
+    v, p, q = (np.zeros((n, n)) for _ in range(3))
+    for _ in range(steps):
+        for i in range(1, n - 1):
+            v[0][i], p[i][0] = 1.0, 0.0
+            q[i][0] = v[0][i]
+            for j in range(1, n - 1):
+                p[i][j] = -c / (a * p[i][j - 1] + b)
+                q[i][j] = (-d * u[j][i - 1] + (1.0 + 2.0 * d) * u[j][i]
+                           - f * u[j][i + 1] - a * q[i][j - 1]) \
+                    / (a * p[i][j - 1] + b)
+            v[n - 1][i] = 1.0
+            for j in range(n - 2, 0, -1):
+                v[j][i] = p[i][j] * v[j + 1][i] + q[i][j]
+        for i in range(1, n - 1):
+            u[i][0], p[i][0] = 1.0, 0.0
+            q[i][0] = u[i][0]
+            for j in range(1, n - 1):
+                p[i][j] = -f / (d * p[i][j - 1] + e)
+                q[i][j] = (-a * v[i - 1][j] + (1.0 + 2.0 * a) * v[i][j]
+                           - c * v[i + 1][j] - d * q[i][j - 1]) \
+                    / (d * p[i][j - 1] + e)
+            u[i][n - 1] = 1.0
+            for j in range(n - 2, 0, -1):
+                u[i][j] = p[i][j] * u[i][j + 1] + q[i][j]
+    return u
+
+
+def _rel_gap(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.abs(got - want).max() / np.abs(want).max()
+
+
+@pytest.mark.parametrize("shape,steps", [((6, 6), 3), ((11, 11), 4)],
+                         ids=["6x6", "11x11"])
+def test_adi_reference_matches_polybench_loop(shape, steps):
+    """The float32 oracle, two sequential solves a sweep, against the C
+    loop nest in float64: the right-hand sides take differences of terms
+    ``2N`` to ``4N`` times the cells', so float32 rounding shows at a few
+    1e-7 of the array's magnitude, not at its last bit."""
+    u0 = np.random.default_rng(9).standard_normal(shape)
+    got = STENCIL_PROGRAMS["adi"].ref(jnp.asarray(u0, jnp.float32), steps)
+    assert _rel_gap(got, _adi_loop(u0, steps)) < 2e-6
+
+
+#: the compiled sweeps against the float32 oracle, as a share of the
+#: oracle's largest magnitude: the row sweep's log-step scans regroup each
+#: recurrence's terms and multiply by reciprocals where the oracle
+#: divides, so the two round apart; the largest gap over these cases,
+#: seeds 0-2 and blocks 1-8, read 5.2e-6
+ADI_REL_TOL = 5e-5
+
+
+@pytest.mark.parametrize("n,steps,block", [(12, 4, 4), (12, 8, 1),
+                                           (40, 4, 8), (40, 8, 4)])
+def test_adi_matches_reference(n, steps, block):
+    """adi through the normal path, in the mode its plan selects, equals
+    the oracle: every sweep over the whole array, boundary rows and
+    columns included."""
+    c = planned("adi").compile(backend="pallas")
+    assert c.mode == "addressable"
+    x = jnp.asarray(np.random.default_rng(n + steps).standard_normal((n, n)),
+                    jnp.float32)
+    want = c.program.ref(x, steps)
+    got = c(x, steps, block)
+    assert got.shape == x.shape
+    assert _rel_gap(got, want) < ADI_REL_TOL
+    assert np.array_equal(got[0], x[0]) and np.array_equal(got[-1], x[-1])
+    assert np.all(np.asarray(got[1:-1, [0, -1]]) == 1.0)
+
+
+def _sequential(c, decay, reverse):
+    """``x_j = c_j + decay_j·x_{j∓1}`` in float64, one lane after another."""
+    x = np.zeros(c.shape)
+    lanes = range(c.shape[-1])
+    for j in (reversed(lanes) if reverse else lanes):
+        k = j + 1 if reverse else j - 1
+        before = x[..., k] if 0 <= k < c.shape[-1] else 0.0
+        x[..., j] = c[..., j] + decay[j] * before
+    return x
+
+
+@pytest.mark.parametrize("reverse", [False, True], ids=["forward", "backward"])
+@pytest.mark.parametrize("width", [5, 64, 130, 2000])
+def test_affine_lane_scan_matches_the_sequential_recurrence(width, reverse):
+    """The log-step scan with per-lane factors (`lane_scan_factors`)
+    equals the recurrence run lane by lane, within float32 rounding of its
+    terms, forward and backward; decays near 0.98 as adi's, so that every
+    one of the ``⌈log2 W⌉`` levels counts."""
+    from repro.runtime.pallas_codegen import (_lane_recurrence,
+                                              lane_scan_factors)
+    rng = np.random.default_rng(width)
+    decay = rng.uniform(0.9, 0.99, width).astype(np.float32)
+    c = rng.standard_normal((8, width)).astype(np.float32)
+    factors = lane_scan_factors(decay, reverse)
+    assert len(factors) == (width - 1).bit_length()
+    got = _lane_recurrence(jnp.asarray(c), list(jnp.asarray(factors)),
+                           reverse)
+    want = _sequential(c.astype(np.float64), decay.astype(np.float64),
+                       reverse)
+    assert _rel_gap(got, want) < 1e-5
+
+
+def test_lane_scan_factors_stop_at_the_first_zero_level():
+    """A decay of 0 on a lane cuts every product that crosses it: with a
+    zero on every other lane, level 1's factors are all 0.0 and the scan
+    stops after level 0; one decay on every lane gives its powers, as
+    `lane_scan_levels` counts them."""
+    from repro.runtime.pallas_codegen import (lane_scan_factors,
+                                              lane_scan_levels)
+    decay = np.tile([0.5, 0.0], 8).astype(np.float32)
+    assert len(lane_scan_factors(decay)) == 1
+    assert len(lane_scan_factors(np.zeros(16, np.float32))) == 0
+    flat = lane_scan_factors(np.full(4000, 1 / 9, np.float32))
+    assert len(flat) == lane_scan_levels(4000, 1 / 9) == 6
+    assert flat[3][-1] == pytest.approx((1 / 9) ** 8, rel=1e-6)
+
+
+def test_adi_plan_selects_addressable_and_refuses_the_ring():
+    """adi's reversed (``p``, ``q``) and transposed (``u``, ``v``) compute
+    channels are planned as reorder buffers, so the plan alone selects the
+    addressable mode, and the ring is refused by name."""
+    a = planned("adi")
+    c = a.compile(backend="pallas")
+    assert c.mode == "addressable"
+    assert set(c.diagnostics["reorder_plans"]) == ADI_OUT_OF_ORDER
+    with pytest.raises(ValueError, match="reorder"):
+        a.compile(backend="pallas", mode="fifo-ring")
+    cheap = dataclasses.replace(a, plans=tuple(
+        dataclasses.replace(p, lowering=FIFO_STREAM) for p in a.plans))
+    with pytest.raises(ValueError, match="adi: line solves have no ring"):
+        cheap.compile(backend="pallas")
+
+
+def test_adi_dependent_steps_counts_the_chain():
+    """Per step the column sweep's ``n − 2`` eliminated and ``n − 2``
+    substituted rows, one after another, then one block of the row sweep:
+    its right-hand side and both scans, all 11 levels each at n = 2000."""
+    adi = STENCIL_PROGRAMS["adi"]
+    assert adi.dependent_steps((2000, 2000), 100, 8) == \
+        100 * (2 * 1998 + 1 + 11 + 11)
+    assert adi.dependent_steps((12, 12), 4, 4) == 4 * (2 * 10 + 1 + 4 + 4)
+
+
+@pytest.mark.parametrize("shape,block,match", [
+    ((12, 16), 4, "square"), ((12, 12), 5, "divide")],
+    ids=["not-square", "block"])
+def test_adi_refuses_other_geometries(shape, block, match):
+    c = planned("adi").compile(backend="pallas")
+    with pytest.raises(ValueError, match=f"adi.*{match}"):
+        c(jnp.zeros(shape, jnp.float32), 4, block)
 
 
 # ------------------------------------------- Analysis.validate on pallas ---

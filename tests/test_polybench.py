@@ -58,8 +58,48 @@ def test_storage_overhead_small():
 
 
 def test_incompleteness_documented():
-    """Paper §3: the method is not complete — lu/cholesky stay partial."""
-    for name in ("lu", "cholesky"):
+    """Paper §3: the method is not complete — lu/cholesky stay partial,
+    and so does adi (`test_adi_channel_verdicts`)."""
+    for name in ("lu", "cholesky", "adi"):
         _, _, rep, _, (n2, f2) = run_kernel(name)
         assert f2 < n2
         assert rep.split_failed or rep.untouched
+
+
+#: adi's compute↔compute channels after FIFOIZE, by verdict.  22 are FIFO:
+#: each recurrence's own carry (``p``, ``q``, ``v``, ``u`` from the line
+#: before), the boundary writes, and the eliminations' ``p`` read by ``q``
+#: in the order written.  10 stay out of order, and a partition cannot
+#: change that: the tiling has one hyperplane, ``t`` (every step's sweeps
+#: read all of ``u`` and ``v``), so a split separates only instances of
+#: different steps, and inside one part the order is still reversed or
+#: transposed.
+ADI_OUT_OF_ORDER = {
+    # reversal: the back substitution reads j = N−2 … 1, written 1 … N−2
+    "cp->cv.p[0]", "cq->cv.q[2]", "rp->ru.p[0]", "rq->ru.q[2]",
+    # transposition: v written by columns, read by rows …
+    "cv->rq.v[0]", "cv->rq.v[1]", "cv->rq.v[2]",
+    # … and u written by rows, read by columns in the next step
+    "ru->cq.u[0]", "ru->cq.u[1]", "ru->cq.u[2]"}
+ADI_FIFO = {
+    "cv0->cq0.v[0]", "cv0->rq.v[0]", "cp0->cp.p[0]", "cp0->cq.p[4]",
+    "cq0->cq.q[3]", "cp->cp.p[0]", "cp->cq.p[4]", "cq->cq.q[3]",
+    "cvn->cv.v[1]", "cvn->rq.v[2]", "cv->cv.v[1]", "ru0->cq.u[0]",
+    "ru0->rq0.u[0]", "rp0->rp.p[0]", "rp0->rq.p[4]", "rq0->rq.q[3]",
+    "rp->rp.p[0]", "rp->rq.p[4]", "rq->rq.q[3]", "run->cq.u[2]",
+    "run->ru.u[1]", "ru->ru.u[1]"}
+
+
+def test_adi_channel_verdicts():
+    """Each of adi's compute channels, before and after FIFOIZE: the
+    out-of-order ones were tried and the split failed, the rest were FIFO
+    from the start."""
+    ppn, ppn2, rep, (n0, f0), (n2, f2) = run_kernel("adi")
+    comp = set(get("adi").compute)
+    verdicts = {c.name: classify_channel(ppn2, c) for c in ppn2.channels
+                if c.producer in comp and c.consumer in comp}
+    assert {n for n, v in verdicts.items() if v is Pattern.FIFO} == ADI_FIFO
+    assert {n for n, v in verdicts.items()
+            if v is Pattern.OOO_UNICITY} == ADI_OUT_OF_ORDER
+    assert (n0, f0) == (n2, f2) == (32, 22)
+    assert set(rep.split_failed) == ADI_OUT_OF_ORDER

@@ -104,12 +104,14 @@ def _compile_stencil(one_chip, name, shape, steps, block, mode=None):
 
 
 #: the smoke's cases, heat-3d at the deepest ring its VMEM estimate
-#: admits (the compiler's limit lies between T=200 and T=240), and the
-#: in-place seidel-2d ring at its benchmark cell's geometry
+#: admits (the compiler's limit lies between T=200 and T=240), the
+#: in-place seidel-2d ring and adi's two sweeps at their benchmark cells'
+#: geometries
 @pytest.mark.parametrize(
     "name,shape,steps,block,mode",
     COMPILER_CASES + (("heat-3d", (128, 128, 128), 200, 8, None),
-                      ("seidel-2d", (4000, 4000), 200, 8, None)),
+                      ("seidel-2d", (4000, 4000), 200, 8, None),
+                      ("adi", (2000, 2000), 100, 8, None)),
     ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
 def test_stencil_kernel_compiles_for_v5e(one_chip, name, shape, steps, block,
                                          mode):
@@ -124,6 +126,14 @@ def test_in_place_kernel_carries_its_name(one_chip):
                                                  (64, 256), 8, 8)
     assert "%_lambda_" in _compile_stencil(one_chip, "jacobi-2d",
                                            (64, 256), 8, 8)
+
+
+def test_adi_kernels_carry_their_names(one_chip):
+    """adi's sweeps are two kernels, each named by its program's
+    ``trace_name``, run once a step inside one loop: one instruction each."""
+    text = _compile_stencil(one_chip, "adi", (64, 64), 4, 8)
+    assert text.count("tpu_custom_call") == 2
+    assert "%adi_column_sweep" in text and "%adi_row_sweep" in text
 
 
 @pytest.mark.parametrize("n_events,ring,order", [
@@ -141,7 +151,10 @@ def test_replay_kernel_compiles_for_v5e(one_chip, n_events, ring, order):
     ("jacobi-1d", (65536,), 64, 64, None, "multiple of 128"),
     ("heat-3d", (128, 128, 128), 256, 8, None, "VMEM"),
     ("jacobi-2d", (4096, 4096), 1, 8, "addressable", "VMEM"),
-], ids=["sublane-block", "lane-block", "ring-vmem", "addressable-vmem"])
+    ("adi", (2000, 2000), 4, 4, None, "adi.*multiple of 8"),
+    ("adi", (2400, 2400), 4, 8, None, "adi column sweep.*VMEM"),
+], ids=["sublane-block", "lane-block", "ring-vmem", "addressable-vmem",
+        "adi-block", "adi-vmem"])
 def test_uncompilable_geometry_is_refused_by_name(one_chip, name, shape,
                                                   steps, block, mode, match):
     with pytest.raises(ValueError, match=match):
